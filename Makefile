@@ -33,7 +33,8 @@ lint:
 	PYTHONPATH=src $(PY) -c "import repro, repro.fl, repro.fl.batched, \
 repro.fl.sharded, repro.comm, repro.core, repro.core.nested, \
 repro.core.population, repro.data, repro.kernels, repro.kernels.sharded, \
-repro.models, repro.launch, repro.launch.mesh, repro.obs, \
+repro.models, repro.launch, repro.launch.mesh, \
+repro.launch.compile_cache, repro.obs, \
 repro.obs.rl, repro.obs.health, repro.obs.slo, repro.obs.export, \
 repro.obs.report, repro.optim, repro.serve, repro.service, repro.sim, \
 repro.train, repro.utils.proptest"

@@ -1,9 +1,12 @@
 """Mesh-scaling benchmark: sharded cohort engine rounds/sec vs device count.
 
-The XLA host-device count is fixed at backend initialization, so each
-device count runs in its own **subprocess** with
+On the CPU backend the XLA host-device count is fixed at backend
+initialization, so each device count runs in its own **subprocess** with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` exported before
-jax imports; the parent collects one JSON row per count and writes
+jax imports. On an accelerator a chip belongs to one process (a parent
+that has touched JAX holds it), so every count runs in-process over a
+``make_debug_mesh(n)`` of the first n devices. The parent collects one
+JSON row per count and writes
 ``artifacts/bench/mesh_scaling.json`` (quick runs write
 ``mesh_scaling_quick.json``, gitignored, so the committed full-budget
 record is never clobbered — same convention as the other benches).
@@ -40,9 +43,10 @@ from pathlib import Path
 # ------------------------------------------------------------------ #
 
 def worker(devices: int, n_clients: int, rounds: int, warmup: int,
-           kd_rows: int, kd_vocab: int) -> dict:
-    assert os.environ.get("XLA_FLAGS", "").find(
-        f"--xla_force_host_platform_device_count={devices}") >= 0
+           kd_rows: int, kd_vocab: int, in_process: bool = False) -> dict:
+    if not in_process:
+        assert os.environ.get("XLA_FLAGS", "").find(
+            f"--xla_force_host_platform_device_count={devices}") >= 0
     import jax
     import numpy as np
     from repro.fl import FLEnvironment, FLSimConfig
@@ -51,7 +55,7 @@ def worker(devices: int, n_clients: int, rounds: int, warmup: int,
     from repro.launch.mesh import make_debug_mesh
     from repro.obs import trace as obs_trace
 
-    assert len(jax.devices()) == devices, jax.devices()
+    assert len(jax.devices()) == devices or in_process, jax.devices()
     mesh = make_debug_mesh(devices)
     cfg = FLSimConfig(dataset="mnist", n_clients=n_clients,
                       k_per_round=n_clients, batches_per_epoch=1,
@@ -129,7 +133,8 @@ def _init_lite(env):
 
 
 # ------------------------------------------------------------------ #
-# parent: one subprocess per device count, assemble the artifact
+# parent: one row per device count (subprocess on CPU), assemble the
+# artifact
 # ------------------------------------------------------------------ #
 
 def _run_worker(devices: int, args_dict: dict) -> dict:
@@ -153,12 +158,20 @@ def _run_worker(devices: int, args_dict: dict) -> dict:
 def main(device_counts=(1, 2, 4), n_clients: int = 64, rounds: int = 3,
          warmup: int = 1, kd_rows: int = 512, kd_vocab: int = 2048,
          artifact_name: str = "mesh_scaling") -> dict:
+    import jax
     from benchmarks.common import emit, save_json
     wargs = {"clients": n_clients, "rounds": rounds, "warmup": warmup,
              "kd_rows": kd_rows, "kd_vocab": kd_vocab}
+    dev = jax.devices()[0]
+    in_process = dev.platform != "cpu"
+    if in_process:
+        device_counts = tuple(n for n in device_counts
+                              if n <= len(jax.devices()))
     rows = {}
     for n in device_counts:
-        rows[str(n)] = _run_worker(n, wargs)
+        rows[str(n)] = (worker(n, n_clients, rounds, warmup, kd_rows,
+                               kd_vocab, in_process=True)
+                        if in_process else _run_worker(n, wargs))
         r = rows[str(n)]
         emit(f"mesh_cohort_d{n}", r["sec_per_round"] * 1e6,
              f"clients={n_clients};rounds_per_sec={r['rounds_per_sec']:.3f}")
@@ -169,7 +182,10 @@ def main(device_counts=(1, 2, 4), n_clients: int = 64, rounds: int = 3,
                 for n in device_counts}
     cores = os.cpu_count()
     max_d = max(device_counts)
-    if cores < max_d:
+    if in_process:
+        note = (f"in-process meshes over the first n of "
+                f"{len(jax.devices())} {dev.device_kind} device(s).")
+    elif cores < max_d:
         note = (f"host has {cores} physical core(s) for {max_d} simulated "
                 f"devices: every shard multiplexes the same core(s), so the "
                 f"curve measures sharding overhead (partitioned dispatch + "
@@ -187,6 +203,7 @@ def main(device_counts=(1, 2, 4), n_clients: int = 64, rounds: int = 3,
                    "batches_per_epoch": 1,
                    "kd_rows": kd_rows, "kd_vocab": kd_vocab},
         "host": {"cpu_count": cores, "note": note},
+        "device": {"platform": dev.platform, "kind": dev.device_kind},
         "rows": rows,
         "scaling": {
             "devices": list(device_counts),

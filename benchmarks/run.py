@@ -29,6 +29,8 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -50,6 +52,7 @@ def main() -> None:
                          "(+ .json sibling) instead of artifacts/bench/"
                          "fleet_health[_quick].md")
     args = ap.parse_args()
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if args.health_report and only is not None:
         only.add("health")         # --health-report implies the bench

@@ -17,9 +17,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro.fl import FLEnvironment, FLSimConfig, HAPFLServer
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     cfg = FLSimConfig(dataset="mnist", n_train=1500, n_test=300,
                       default_epochs=8, batches_per_epoch=2, lr=1e-2)
     env = FLEnvironment(cfg)

@@ -74,6 +74,18 @@ def make_train_one(raw_step, init_opt, unroll: int = 4):
     return train_one
 
 
+def cohort_step_fns(cfg, lite_cfg, lr: float):
+    """Un-jitted mutual-KD (step, init_opt) of one size group: the `cfg`
+    local model paired with the `lite_cfg` LiteModel. apply_cnn_fast
+    (im2col convs + slice pooling) is numerically equivalent to apply_cnn
+    but efficient under vmap on CPU."""
+    return make_mutual_train_fns(
+        functools.partial(lambda p, x, cc: apply_cnn_fast(p, cc, x), cc=cfg),
+        functools.partial(lambda p, x, cc: apply_cnn_fast(p, cc, x),
+                          cc=lite_cfg),
+        lr=lr)
+
+
 def make_batched_trainer(raw_step, init_opt, unroll: int = 4):
     """Compile (stacked_params, xs, ys, mask) -> trained stacked_params.
 
@@ -114,18 +126,9 @@ class BatchedClientEngine:
     def __init__(self, env, lr: float = None):
         self.env = env
         lr = env.cfg.lr if lr is None else lr
-        self._trainers = {}
-        for s, c in env.pool.items():
-            # apply_cnn_fast: im2col convs + slice pooling — numerically
-            # equivalent to apply_cnn but efficient under vmap on CPU
-            raw, init_opt = make_mutual_train_fns(
-                functools.partial(
-                    lambda p, x, cc: apply_cnn_fast(p, cc, x), cc=c),
-                functools.partial(
-                    lambda p, x, cc: apply_cnn_fast(p, cc, x),
-                    cc=env.lite_cfg),
-                lr=lr)
-            self._trainers[s] = self._build_trainer(raw, init_opt)
+        self._trainers = {
+            s: self._build_trainer(*cohort_step_fns(c, env.lite_cfg, lr))
+            for s, c in env.pool.items()}
 
     # hooks the mesh-sharded subclass (fl/sharded.py) overrides ---------- #
     def _build_trainer(self, raw_step, init_opt):

@@ -28,13 +28,15 @@ the batched engine) AND up to a multiple of the mesh's data-axis size, so
 every device holds the same number of (possibly fully-masked) clients —
 `pad_to_mesh` below is the invariant, pinned in tests/test_sharded.py.
 
-Everything runs on CPU by simulating devices:
+On chips, `launch.mesh.make_debug_mesh(n)` builds the (data, model) mesh
+over the first n devices, all in one process (`chip_smoke.py --chips 4`
+runs this engine over four v5e chips against the batched engine on one).
+The CPU tests simulate devices instead:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 python ...
 
-(the flag must be set before jax initializes; tests/bench_mesh use
-subprocesses). `launch.mesh.make_debug_mesh` then builds the (data,
-model) mesh over the simulated devices.
+(the flag must be set before jax initializes, so those tests and the CPU
+form of bench_mesh use subprocesses).
 """
 from __future__ import annotations
 

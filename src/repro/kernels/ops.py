@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On the CPU container kernels run in ``interpret=True`` (Python-level
-execution of the kernel body) for correctness validation; on a real TPU
-backend ``on_tpu()`` flips them to compiled mode.
+On the CPU backend, which the tests force, kernels run with
+``interpret=True`` (the kernel body executed as plain JAX ops) for
+correctness validation. On any other backend they are compiled: a TPU
+runs the Mosaic kernel (its lowered program holds a ``tpu_custom_call``),
+and no accelerator falls back to interpret mode in silence.
 """
 from __future__ import annotations
 
@@ -16,8 +18,9 @@ from repro.kernels.rmsnorm import rmsnorm as _rms
 from repro.obs.trace import current as _tracer
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """True only on the CPU backend (the tests); accelerators compile."""
+    return jax.default_backend() == "cpu"
 
 
 def flash_attention_op(q, k, v, *, causal=True, sliding_window=0,
@@ -26,20 +29,20 @@ def flash_attention_op(q, k, v, *, causal=True, sliding_window=0,
     with _tracer().annotation("pallas.flash_attention"):
         return _flash(q, k, v, causal=causal, sliding_window=sliding_window,
                       block_q=block_q, block_k=block_k,
-                      interpret=not on_tpu())
+                      interpret=interpret_mode())
 
 
 def kd_loss_op(x_logits, y_logits, labels, *, block_n=256, block_v=512):
     """(N, V) x 2 + (N,) labels -> per-row {ce_x, ce_y, kl_xy, kl_yx}."""
     with _tracer().annotation("pallas.kd_loss"):
         return _kd(x_logits, y_logits, labels, block_n=block_n,
-                   block_v=block_v, interpret=not on_tpu())
+                   block_v=block_v, interpret=interpret_mode())
 
 
 def rmsnorm_op(x, scale, *, block_n=256, eps=1e-5):
     with _tracer().annotation("pallas.rmsnorm"):
         return _rms(x, scale, block_n=block_n, eps=eps,
-                    interpret=not on_tpu())
+                    interpret=interpret_mode())
 
 
 def mutual_kd_loss(x_logits, y_logits, labels, lambdas=(0.4, 0.6, 0.5, 0.5),

@@ -3,8 +3,8 @@
 Each wrapper `shard_map`s the corresponding `ops.py` kernel over the
 `data` axis of a mesh: the leading axis (logit rows for kd_loss/rmsnorm,
 batch for flash_attention) is split into per-device shards and every
-device runs the *actual Pallas kernel body* (interpret mode off-TPU, see
-docs/kernels.md §2) on its shard. All three ops are row-independent, so
+device runs the *actual Pallas kernel body* (interpret mode on the CPU
+backend, see docs/kernels.md §2) on its shard. All three ops are row-independent, so
 the sharded programs contain no collectives and agree with the
 single-device kernels exactly (pinned in tests/test_sharded.py).
 
@@ -19,11 +19,6 @@ import functools
 
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                   # jax >= 0.4.35
-    from jax.experimental.shard_map import shard_map
-except ImportError:                    # pragma: no cover - newer jax
-    from jax.sharding import shard_map
 
 from repro.kernels.ops import flash_attention_op, kd_loss_op, rmsnorm_op
 from repro.obs.trace import current as _tracer
@@ -44,11 +39,11 @@ def sharded_kd_loss(x_logits, y_logits, labels, mesh: Mesh,
     satisfy the kernel's own row-block constraint (block_n is clamped to
     the shard size, so pow2 shard sizes always work)."""
     _check_divisible(x_logits.shape[0], mesh, axis, "rows")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(kd_loss_op, block_n=block_n, block_v=block_v),
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis)),
-        out_specs=P(axis), check_rep=False)
+        out_specs=P(axis), check_vma=False)
     with _tracer().annotation(f"sharded.kd_loss@{mesh.shape[axis]}"):
         return fn(x_logits, y_logits, labels)
 
@@ -57,10 +52,10 @@ def sharded_rmsnorm(x, scale, mesh: Mesh, axis: str = "data", *,
                     block_n: int = 256, eps: float = 1e-5):
     """(N, D) row-sharded rmsnorm; the (D,) scale is replicated."""
     _check_divisible(x.shape[0], mesh, axis, "rows")
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(rmsnorm_op, block_n=block_n, eps=eps),
         mesh=mesh, in_specs=(P(axis, None), P(None)),
-        out_specs=P(axis, None), check_rep=False)
+        out_specs=P(axis, None), check_vma=False)
     with _tracer().annotation(f"sharded.rmsnorm@{mesh.shape[axis]}"):
         return fn(x, scale)
 
@@ -71,11 +66,11 @@ def sharded_flash_attention(q, k, v, mesh: Mesh, axis: str = "data", *,
     """(B, H, S, hd) attention with the batch axis split over the mesh."""
     _check_divisible(q.shape[0], mesh, axis, "batch")
     spec = P(axis, None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(flash_attention_op, causal=causal,
                           sliding_window=sliding_window,
                           block_q=block_q, block_k=block_k),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     with _tracer().annotation(f"sharded.flash_attention@{mesh.shape[axis]}"):
         return fn(q, k, v)

@@ -16,6 +16,7 @@ import argparse
 from repro.comm import make_codec
 from repro.core.latency import AvailabilityModel
 from repro.fl import FLEnvironment, FLSimConfig, HAPFLServer
+from repro.launch.compile_cache import use_compile_cache
 from repro.service import (LoadGenerator, ParamService, latest_checkpoint,
                            poisson_trace)
 
@@ -79,6 +80,7 @@ def main():
                     help="tee the structured event log into an append-only "
                          "JSONL stream with rotation")
     args = ap.parse_args()
+    use_compile_cache()
 
     tracer = None
     if args.trace:

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.data.synthetic import make_token_dataset
 from repro.models.api import dummy_batch
 from repro.train.step import (TrainStepConfig, make_hapfl_train_step,
@@ -54,6 +55,7 @@ def main():
                     help="reduced config (CPU-trainable ~100M-class)")
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

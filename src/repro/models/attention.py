@@ -79,13 +79,8 @@ def flash_decode_shardmap(q, cache_k, cache_v, k_new, v_new, slot, kv_valid,
     cache_spec = P(bspec, "model", None, None)
     in_specs = (rep, cache_spec, cache_spec, rep, rep, P(), P())
     out_specs = (rep, cache_spec, cache_spec)
-    try:
-        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        fn = _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(q, cache_k, cache_v, k_new, v_new,
               jnp.asarray(slot, jnp.int32), jnp.asarray(kv_valid, jnp.int32))
 

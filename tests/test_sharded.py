@@ -248,13 +248,16 @@ for n in (1, 2, 4):
                           jax.tree_util.tree_leaves(tg)):
             np.testing.assert_allclose(np.asarray(lr), np.asarray(lg),
                                        atol=1e-5, rtol=1e-4)
-    # pad-invariance on the multi-device mesh: ragged 2-client group
+    # pad-invariance on the multi-device mesh: ragged 2-client group, each
+    # side on fresh loaders (eng's have already drawn client 1's batches)
     exact = ShardedClientEngine(FLEnvironment(CFG),
                                 mesh=make_debug_mesh(n)).train_cohort(
         [1, 4], ["small", "small"], [1, 3],
         srv.global_by_size, srv.lite_params, pad_pow2=False)
-    padded = eng.train_cohort([1, 4], ["small", "small"], [1, 3],
-                              srv.global_by_size, srv.lite_params)
+    padded = ShardedClientEngine(FLEnvironment(CFG),
+                                 mesh=make_debug_mesh(n)).train_cohort(
+        [1, 4], ["small", "small"], [1, 3],
+        srv.global_by_size, srv.lite_params)
     for tp, te in zip(padded, exact):
         for lp, le in zip(jax.tree_util.tree_leaves(tp),
                           jax.tree_util.tree_leaves(te)):
