@@ -37,6 +37,9 @@ def _build(seed: int):
                       max_speed_ratio=10.0, seed=seed)
     env = FLEnvironment(cfg)
     srv = HAPFLServer(env, seed=seed, codec="int8")
+    # the per-wave RL diagnostic counters need their own switch: tracing
+    # alone does not collect them
+    srv.collect_rl_diag = True
     comm = make_comm_model(
         {s: float(c.num_params()) for s, c in env.pool.items()},
         float(env.lite_cfg.num_params()), cfg.n_clients, mean_mbps=1.0,

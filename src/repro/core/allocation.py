@@ -6,6 +6,7 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from repro.core.ppo import PPOAgent, PPOConfig
+from repro.obs.trace import current as _tracer
 
 
 class ModelAllocator:
@@ -28,7 +29,7 @@ class ModelAllocator:
                         n_categories=len(size_names), lr=lr,
                         buffer_size=buffer_size, gamma=gamma,
                         update_epochs=update_epochs, entropy_coef=0.003)
-        self.agent = PPOAgent(cfg, key)
+        self.agent = PPOAgent(cfg, key, name="ppo1")
         self._pending: Dict = {}
 
     @staticmethod
@@ -50,10 +51,11 @@ class ModelAllocator:
     def feedback(self, local_times: Sequence[float],
                  intensities: Sequence[float]) -> float:
         """Reward from this round's measured per-epoch times (Eqs. 20-23)."""
-        t = np.asarray(local_times, np.float64)
-        tau = np.maximum(np.asarray(intensities, np.float64), 1.0)
-        t_avg = t / tau
-        reward = self.md - t_avg.max() / max(t_avg.min(), 1e-9)
+        with _tracer().span("latency.rewards"):
+            t = np.asarray(local_times, np.float64)
+            tau = np.maximum(np.asarray(intensities, np.float64), 1.0)
+            t_avg = t / tau
+            reward = self.md - t_avg.max() / max(t_avg.min(), 1e-9)
         self.agent.store(self._pending["state"], self._pending["action"],
                          self._pending["logprob"], reward)
         self.agent.maybe_update()

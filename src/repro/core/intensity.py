@@ -6,6 +6,7 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from repro.core.ppo import PPOAgent, PPOConfig
+from repro.obs.trace import current as _tracer
 
 
 def _softmax(v: np.ndarray) -> np.ndarray:
@@ -30,7 +31,7 @@ class IntensityAllocator:
         cfg = PPOConfig(state_dim=k, kind="gaussian_simplex", lr=lr,
                         buffer_size=buffer_size, gamma=gamma,
                         update_epochs=update_epochs)
-        self.agent = PPOAgent(cfg, key)
+        self.agent = PPOAgent(cfg, key, name="ppo2")
         self._pending: Dict = {}
 
     def assign(self, key, modified_times: Sequence[float],
@@ -45,8 +46,9 @@ class IntensityAllocator:
         return [int(t) for t in tau], sigma
 
     def feedback(self, local_times: Sequence[float]) -> float:
-        t = np.asarray(local_times, np.float64)
-        reward = float(t.min() - t.max())                          # Eq. 28
+        with _tracer().span("latency.rewards"):
+            t = np.asarray(local_times, np.float64)
+            reward = float(t.min() - t.max())                      # Eq. 28
         self.agent.store(self._pending["state"], self._pending["action"],
                          self._pending["logprob"], reward)
         self.agent.maybe_update()

@@ -13,7 +13,6 @@ and run the clipped-PPO update (Eqs. 29-32) once the buffer is full
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -21,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import current as _tracer
 from repro.optim import adamw
 
 
@@ -63,10 +63,19 @@ class PPOConfig:
 
 
 class PPOAgent:
-    """Stateful wrapper: jit-compiled act/update, python-side buffer."""
+    """Stateful wrapper: jit-compiled act/update, python-side buffer.
 
-    def __init__(self, cfg: PPOConfig, key):
+    `name` ("ppo1", "ppo2") names the agent's trace spans: `<name>.act`,
+    and `<name>.update` when the buffer was full. Each holds the child
+    spans `ppo.put` (host->device), `ppo.dispatch` (the jitted call) and
+    `ppo.sync` (the blocking device->host reads); the counts `ppo.launches`
+    and `ppo.syncs` tally programs called and reads made (DESIGN.md §16).
+    """
+
+    def __init__(self, cfg: PPOConfig, key, name: str = "ppo"):
         self.cfg = cfg
+        self.name = name
+        self._span_act, self._span_update = f"{name}.act", f"{name}.update"
         k1, k2, k3 = jax.random.split(key, 3)
         out_dim = (cfg.state_dim * cfg.n_categories
                    if cfg.kind == "categorical_multihead" else cfg.state_dim)
@@ -83,15 +92,31 @@ class PPOAgent:
         self.reward_history: List[float] = []
         self.last_update: Optional[Dict[str, float]] = None
         self.n_updates = 0
-        self._act = jax.jit(functools.partial(_act, cfg=cfg),
-                            static_argnames=("deterministic",))
-        self._update = jax.jit(functools.partial(_ppo_update, cfg=cfg))
+        # named functions, so the programs compile as jit_ppo_act and
+        # jit_ppo_update (a bare partial compiles as jit__unknown)
+        def ppo_act(params, key, state, deterministic):
+            return _act(params, key, state, deterministic, cfg=cfg)
+
+        def ppo_update(params, opt_state, batch):
+            return _ppo_update(params, opt_state, batch, cfg=cfg)
+
+        self._act = jax.jit(ppo_act, static_argnames=("deterministic",))
+        self._update = jax.jit(ppo_update)
 
     # ------------------------------------------------------------------ #
     def act(self, key, state: np.ndarray, deterministic: bool = False):
-        action, logprob = self._act(self.params, key, jnp.asarray(state),
-                                    deterministic)
-        return np.asarray(action), float(logprob)
+        tr = _tracer()
+        with tr.span(self._span_act):
+            with tr.span("ppo.put"):
+                x = jnp.asarray(state)
+            with tr.span("ppo.dispatch"):
+                action, logprob = self._act(self.params, key, x,
+                                            deterministic)
+            with tr.span("ppo.sync"):
+                out = np.asarray(action), float(logprob)
+            tr.count("ppo.launches")
+            tr.count("ppo.syncs", 2)
+        return out
 
     def store(self, state, action, logprob, reward):
         self.buffer.append({"state": np.asarray(state, np.float32),
@@ -104,12 +129,19 @@ class PPOAgent:
         """Algorithm 1: update once the buffer is full, then clear it."""
         if len(self.buffer) < self.cfg.buffer_size:
             return None
-        batch = {k: jnp.asarray(np.stack([b[k] for b in self.buffer]))
-                 for k in self.buffer[0]}
-        self.params, self.opt_state, metrics = self._update(
-            self.params, self.opt_state, batch)
-        self.buffer.clear()
-        out = {k: float(v) for k, v in metrics.items()}
+        tr = _tracer()
+        with tr.span(self._span_update):
+            with tr.span("ppo.put"):
+                batch = {k: jnp.asarray(np.stack([b[k] for b in self.buffer]))
+                         for k in self.buffer[0]}
+            with tr.span("ppo.dispatch"):
+                self.params, self.opt_state, metrics = self._update(
+                    self.params, self.opt_state, batch)
+            tr.count("ppo.launches")
+            self.buffer.clear()
+            with tr.span("ppo.sync"):
+                out = {k: float(v) for k, v in metrics.items()}
+            tr.count("ppo.syncs", len(out))
         self.last_update = out
         self.n_updates += 1
         return out

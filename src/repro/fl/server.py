@@ -52,9 +52,9 @@ class RoundRecord:
     acc_by_size: Dict[str, float]
     client_acc: Dict[int, Dict[str, float]]
     latency_only: bool = False
-    #: per-wave PPO diagnostics (repro.obs.rl) — populated only when
-    #: tracing is enabled, None otherwise (so untraced runs stay
-    #: byte-identical to pre-observability ones)
+    #: per-wave PPO diagnostics (repro.obs.rl) — populated only under
+    #: `HAPFLServer.collect_rl_diag`, None otherwise (tracing alone does
+    #: not collect them, so traced and untraced runs do the same work)
     rl_diag: Optional[Dict[str, Dict]] = None
 
 
@@ -170,9 +170,9 @@ class HAPFLServer:
         self.history: List[RoundRecord] = []
         self._round = 0
         self._last_rl_diag: Optional[Dict[str, Dict]] = None
-        # set by a scheduler/service with a FleetHealth attached: collect
-        # per-wave PPO diagnostics even when tracing is off (the trace
-        # counter emits stay no-ops; only RoundRecord.rl_diag fills in)
+        # the one switch for per-wave PPO diagnostics, set by a
+        # scheduler/service with a FleetHealth attached (RoundRecord.rl_diag
+        # fills in; the rl.* trace counters are emitted when also traced)
         self.collect_rl_diag = False
 
     # ------------------------------------------------------------------ #
@@ -225,6 +225,7 @@ class HAPFLServer:
 
     def _plan_wave(self, clients, latency_only, deterministic) -> WavePlan:
         env, cfg = self.env, self.env.cfg
+        tr = _tracer()
         r = self._round
         self._round += 1
         if clients is None:
@@ -235,14 +236,18 @@ class HAPFLServer:
         # time) — one vectorized pass over the ClientStore, or the legacy
         # per-profile loop; element-for-element bitwise identical (the
         # scalar latency path delegates to the same numpy kernels)
-        if self.store is not None:
-            assess = [float(a) for a in
-                      env.latency.assessment_times(self.store, clients, r)]
-        else:
-            assess = [env.latency.assessment_time(env.profiles[c], r)
-                      for c in clients]
+        with tr.span("latency.assess"):
+            if self.store is not None:
+                assess = [float(a) for a in env.latency.assessment_times(
+                    self.store, clients, r)]
+            else:
+                assess = [env.latency.assessment_time(env.profiles[c], r)
+                          for c in clients]
         # 2. PPO1: model allocation
-        self.key, k1, k2 = jax.random.split(self.key, 3)
+        with tr.span("server.split_key"):
+            # two programs: the split and the unstacking of its result
+            self.key, k1, k2 = jax.random.split(self.key, 3)
+        tr.count("ppo.launches", 2)
         if self.use_ppo1:
             sizes, _ = self.allocator.allocate(k1, self._pad(assess),
                                                deterministic)
@@ -260,15 +265,18 @@ class HAPFLServer:
             intensities = intensities[:m]
         else:
             intensities = [cfg.default_epochs] * m
-        if self.store is not None:
-            local_times = [float(t) for t in env.latency.local_train_times(
-                self.store, clients, r, sizes, intensities)]
-            self.store.note_plan(clients, assess, local_times, sizes,
-                                 intensities)
-        else:
-            local_times = [env.latency.local_train_time(env.profiles[c], r,
-                                                        s, tau)
-                           for c, s, tau in zip(clients, sizes, intensities)]
+        with tr.span("latency.local_times"):
+            if self.store is not None:
+                local_times = [float(t) for t in
+                               env.latency.local_train_times(
+                                   self.store, clients, r, sizes,
+                                   intensities)]
+                self.store.note_plan(clients, assess, local_times, sizes,
+                                     intensities)
+            else:
+                local_times = [env.latency.local_train_time(
+                    env.profiles[c], r, s, tau)
+                    for c, s, tau in zip(clients, sizes, intensities)]
         return WavePlan(round_idx=r, clients=clients, assess=assess,
                         sizes=sizes, intensities=list(intensities),
                         local_times=local_times, latency_only=latency_only)
@@ -429,10 +437,11 @@ class HAPFLServer:
         return len(updates)
 
     def feedback_wave(self, plan: WavePlan):
-        """Step 6: RL rewards (Algorithm 1 lines 22-30). With tracing on
-        (or `collect_rl_diag` set by a health-tracking caller), also
-        collects both agents' PPO diagnostics (repro.obs.rl), emits them
-        as trace counters, and stages them for `record_wave`."""
+        """Step 6: RL rewards (Algorithm 1 lines 22-30). With
+        `collect_rl_diag` set (by a health-tracking caller), also collects
+        both agents' PPO diagnostics (repro.obs.rl), emits them as trace
+        counters, and stages them for `record_wave`. Tracing alone does
+        not collect them, so a traced round does the same work."""
         tr = _tracer()
         with tr.span("server.feedback_wave", round=plan.round_idx):
             rw1 = (self.allocator.feedback(self._pad(plan.local_times),
@@ -440,8 +449,7 @@ class HAPFLServer:
                    if self.use_ppo1 else 0.0)
             rw2 = (self.intensity.feedback(self._pad(plan.local_times))
                    if self.use_ppo2 else 0.0)
-        if ((tr.enabled or self.collect_rl_diag)
-                and (self.use_ppo1 or self.use_ppo2)):
+        if self.collect_rl_diag and (self.use_ppo1 or self.use_ppo2):
             from repro.obs.rl import wave_diagnostics
             diag = wave_diagnostics(self)
             for agent_name, d in diag.items():

@@ -15,8 +15,10 @@ per-wave diagnostics mix two sources:
 
 `wave_diagnostics(server)` packages both agents' views; the server emits
 it as trace counters and stamps it on the round record (`rl_diag`) — only
-when tracing is enabled, so disabled runs stay byte-identical to
-uninstrumented ones (pinned in tests/test_obs.py).
+when `HAPFLServer.collect_rl_diag` is set (a health-tracking caller sets
+it). Tracing alone does not collect them: the entropy forwards would make
+a traced round do work an untraced one does not. Collecting them never
+changes the simulation's outputs (pinned in tests/test_obs.py).
 """
 from __future__ import annotations
 
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.ppo import PPOAgent, _policy_dist
+from repro.obs.trace import current as _tracer
 
 #: last_update keys surfaced per wave (the full dict also carries
 #: loss/actor_loss/critic_loss/mean_ratio/mean_return)
@@ -36,12 +39,13 @@ UPDATE_KEYS = ("approx_kl", "clip_fraction", "adv_mean", "adv_std",
 
 def _entropy_fn(agent: PPOAgent):
     """Jitted state -> policy entropy for this agent's head, cached on the
-    agent (one compile per agent, reused every wave)."""
+    agent (one compile per agent, reused every wave); it compiles as
+    jit_ppo_entropy."""
     fn = getattr(agent, "_obs_entropy_fn", None)
     if fn is None:
         cfg = agent.cfg
 
-        def ent(params, state):
+        def ppo_entropy(params, state):
             dist = _policy_dist(params, state, cfg)
             if cfg.kind == "categorical_multihead":
                 logp = dist["logits"]                  # (k, delta) log-probs
@@ -50,7 +54,7 @@ def _entropy_fn(agent: PPOAgent):
             return jnp.sum(dist["log_std"]
                            + 0.5 * jnp.log(2 * jnp.pi * jnp.e))
 
-        fn = jax.jit(ent)
+        fn = jax.jit(ppo_entropy)
         agent._obs_entropy_fn = fn
     return fn
 
@@ -58,8 +62,12 @@ def _entropy_fn(agent: PPOAgent):
 def policy_entropy(agent: PPOAgent, state) -> float:
     """Entropy of the agent's current policy at `state` (nats; summed over
     the per-client heads for PPO1, over action dims for PPO2)."""
-    return float(_entropy_fn(agent)(agent.params,
-                                    jnp.asarray(np.asarray(state))))
+    tr = _tracer()
+    out = float(_entropy_fn(agent)(agent.params,
+                                   jnp.asarray(np.asarray(state))))
+    tr.count("ppo.launches")
+    tr.count("ppo.syncs")
+    return out
 
 
 def agent_diagnostics(owner) -> Dict[str, Optional[float]]:

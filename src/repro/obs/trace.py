@@ -16,6 +16,15 @@ Virtual-clock events carry no wall timestamps at all, so two bit-identical
 simulation runs produce bit-identical virtual event streams — the tracer
 determinism pin in tests/test_obs.py relies on this.
 
+Wall spans form a tree: each records the name of the wall span that
+encloses it (`parent`, None at the top), so a reader can compute self
+time, and a span opened inside one that carries a `round` argument
+inherits that round index. `count(name, n)` keeps monotonic totals in
+`counts` (no time series). `wall0` is the `perf_counter` value the wall
+clock counts from, and `host_intervals()` returns the wall spans as
+`(name, start, end)` on the `perf_counter` clock itself, the clock on
+which a profiler trace's host-side wrappers are placed.
+
 Tracing is off by default: the module-level singleton is a `NullTracer`
 whose `enabled` attribute is False and whose methods are allocation-free
 no-ops returning one shared null context manager. Instrumented hot paths
@@ -81,6 +90,9 @@ class NullTracer:
     def counter(self, name, values, clock=WALL, tid=None, t=None):
         return None
 
+    def count(self, name, n=1):
+        return None
+
     def set_virtual(self, t):
         return None
 
@@ -93,9 +105,11 @@ NULL_TRACER = NullTracer()
 
 class _Span:
     """Live wall/virtual span: records begin time on enter, appends one
-    "X" complete event on exit."""
+    "X" complete event on exit. A wall span also takes its parent (and
+    the parent's round index) from the tracer's stack of open wall
+    spans."""
 
-    __slots__ = ("tracer", "name", "clock", "tid", "args", "_t0")
+    __slots__ = ("tracer", "name", "clock", "tid", "args", "_t0", "_parent")
 
     def __init__(self, tracer, name, clock, tid, args):
         self.tracer = tracer
@@ -105,13 +119,26 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        self._t0 = self.tracer._now(self.clock)
+        tr = self.tracer
+        if self.clock == WALL:
+            stack = tr._open
+            parent = stack[-1] if stack else None
+            self._parent = parent
+            if (parent is not None and "round" not in self.args
+                    and "round" in parent.args):
+                self.args["round"] = parent.args["round"]
+            stack.append(self)
+        self._t0 = tr._now(self.clock)
         return self
 
     def __exit__(self, *exc):
         tr = self.tracer
-        tr._push(self.name, "X", self.clock, self.tid, self._t0,
-                 tr._now(self.clock) - self._t0, self.args)
+        ev = tr._push(self.name, "X", self.clock, self.tid, self._t0,
+                      tr._now(self.clock) - self._t0, self.args)
+        if self.clock == WALL:
+            parent = self._parent
+            ev["parent"] = None if parent is None else parent.name
+            tr._open.remove(self)
         return False
 
 
@@ -123,14 +150,18 @@ class Tracer:
     enabled = True
 
     def __init__(self):
-        self._wall0 = time.perf_counter()
+        #: the `perf_counter` value the wall clock counts from
+        self.wall0 = time.perf_counter()
         self._vnow = 0.0
         self.events: List[Dict] = []
+        #: monotonic totals of `count`
+        self.counts: Dict[str, float] = {}
+        self._open: List[_Span] = []     # open wall spans, innermost last
 
     # ------------------------------------------------------------------ #
     def _now(self, clock: str) -> float:
         if clock == WALL:
-            return time.perf_counter() - self._wall0
+            return time.perf_counter() - self.wall0
         return self._vnow
 
     def set_virtual(self, t: float) -> None:
@@ -176,6 +207,10 @@ class Tracer:
         ts = self._now(clock) if t is None else float(t)
         return self._push(name, "C", clock, tid or name, ts, None, vals)
 
+    def count(self, name, n=1) -> None:
+        """Add `n` to the monotonic total `counts[name]`."""
+        self.counts[name] = self.counts.get(name, 0) + n
+
     def annotation(self, name):
         """A named block that lands both in this tracer (wall span) and in
         any active `jax.profiler` trace (`TraceAnnotation`) — used around
@@ -215,9 +250,18 @@ class Tracer:
                         ev["ph"], ev["name"], str(ev["tid"]), args))
         return sorted(out)
 
+    def host_intervals(self) -> List:
+        """`(name, start, end)` of every wall span, in seconds on the
+        `perf_counter` clock (not relative to `wall0`)."""
+        w0 = self.wall0
+        return [(ev["name"], w0 + ev["ts"], w0 + ev["ts"] + ev["dur"])
+                for ev in self.events
+                if ev["ph"] == "X" and ev["clock"] == WALL]
+
     def clear(self) -> None:
         self.events.clear()
-        self._wall0 = time.perf_counter()
+        self.counts.clear()
+        self.wall0 = time.perf_counter()
         self._vnow = 0.0
 
     # ------------------------------------------------------------------ #

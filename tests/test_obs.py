@@ -153,6 +153,108 @@ def test_wave_timing_summary():
     assert wave_timing_summary([]) is None
 
 
+def _wall_spans(tracer, name=None):
+    return [e for e in tracer.events if e["ph"] == "X"
+            and e["clock"] == WALL and (name is None or e["name"] == name)]
+
+
+def test_wall_spans_record_their_parent():
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("mid"):
+            with tr.span("leaf"):
+                pass
+        with tr.span("sibling"):
+            pass
+    with tr.span("alone"):
+        pass
+    parent = {e["name"]: e["parent"] for e in _wall_spans(tr)}
+    assert parent == {"outer": None, "mid": "outer", "leaf": "mid",
+                      "sibling": "outer", "alone": None}
+    # virtual spans stay out of the wall tree
+    with tr.span("outer2"):
+        with tr.span("v", clock=VIRTUAL):
+            with tr.span("inner2"):
+                pass
+    v = next(e for e in tr.events if e["name"] == "v")
+    assert "parent" not in v
+    assert _wall_spans(tr, "inner2")[0]["parent"] == "outer2"
+
+
+def test_self_time_from_parents_sums_to_the_top_spans():
+    tr = Tracer()
+    with tr.span("top"):
+        with tr.span("a"):
+            time.sleep(0.002)
+        with tr.span("b"):
+            with tr.span("c"):
+                time.sleep(0.001)
+    spans = _wall_spans(tr)
+    total = {e["name"]: e["dur"] for e in spans}
+    child = {}
+    for e in spans:
+        if e["parent"] is not None:
+            child[e["parent"]] = child.get(e["parent"], 0.0) + e["dur"]
+    self_t = {n: total[n] - child.get(n, 0.0) for n in total}
+    assert all(v >= 0.0 for v in self_t.values())
+    assert sum(self_t.values()) == pytest.approx(total["top"], abs=1e-12)
+
+
+def test_nested_spans_inherit_the_round_index():
+    tr = Tracer()
+    with tr.span("server.plan_wave", round=7):
+        with tr.span("ppo1.act"):
+            with tr.span("ppo.sync"):
+                pass
+        with tr.span("other", round=9):     # an explicit round wins
+            with tr.span("deep"):
+                pass
+    with tr.span("outside"):
+        pass
+    rnd = {e["name"]: e.get("args", {}).get("round")
+           for e in _wall_spans(tr)}
+    assert rnd == {"server.plan_wave": 7, "ppo1.act": 7, "ppo.sync": 7,
+                   "other": 9, "deep": 9, "outside": None}
+
+
+def test_count_keeps_monotonic_totals():
+    tr = Tracer()
+    assert tr.counts == {}
+    tr.count("ppo.syncs")
+    tr.count("ppo.syncs", 11)
+    tr.count("ppo.launches", 2)
+    assert tr.counts == {"ppo.syncs": 12, "ppo.launches": 2}
+    # counts are totals, not trace events
+    assert not tr.events
+    tr.clear()
+    assert tr.counts == {}
+    assert NULL_TRACER.count("ppo.syncs", 3) is None
+    assert not hasattr(NULL_TRACER, "counts")
+
+
+def test_host_intervals_are_on_the_perf_counter_clock():
+    tr = Tracer()
+    assert tr.wall0 <= time.perf_counter()
+    before = time.perf_counter()
+    with tr.span("a"):
+        with tr.span("b"):
+            time.sleep(0.001)
+    after = time.perf_counter()
+    tr.span_at("v", 0.0, 1.0, clock=VIRTUAL)          # not a wall span
+    got = tr.host_intervals()
+    assert [n for n, _, _ in got] == ["b", "a"]       # in closing order
+    (_, b0, b1), (_, a0, a1) = got
+    assert before <= a0 <= b0 < b1 <= a1 <= after
+    assert b1 - b0 >= 0.001
+    # clear() moves the origin; new spans stay on the same absolute clock
+    tr.clear()
+    t = time.perf_counter()
+    with tr.span("c"):
+        pass
+    (_, c0, _), = tr.host_intervals()
+    assert c0 >= t
+
+
 # --------------------------------------------------------------------- #
 # tracer determinism + zero-cost disable against the simulator
 # --------------------------------------------------------------------- #
@@ -179,24 +281,26 @@ def test_virtual_records_deterministic_across_runs():
 
 def test_tracing_does_not_perturb_the_simulation():
     """A traced run must be byte-identical to an untraced one on every
-    simulation output (records differ only in the rl_diag side channel)."""
+    simulation output. Tracing alone collects no RL diagnostics
+    (`collect_rl_diag` is the one switch), so the records are equal
+    whole, rl_diag (None on both sides) included."""
     srv_a = fresh_server()
     res_a = EventScheduler(srv_a, SyncPolicy()).run(waves=3)
-    srv_b, res_b, _ = _traced_sim_run_sync()
+    srv_b, res_b, _ = _traced_sim_run_sync(collect_rl_diag=False)
+    assert len(srv_a.history) == len(srv_b.history) == 3
     for a, b in zip(srv_a.history, srv_b.history):
-        assert a.rl_diag is None and b.rl_diag is not None
-        da, db = dataclasses.asdict(a), dataclasses.asdict(b)
-        da.pop("rl_diag"), db.pop("rl_diag")
-        assert da == db
+        assert a.rl_diag is None and b.rl_diag is None
+        assert dataclasses.asdict(a) == dataclasses.asdict(b)
     assert res_a.sim_time == res_b.sim_time
     assert res_a.timing is None and res_b.timing is not None
 
 
-def _traced_sim_run_sync(seed=3, waves=3):
+def _traced_sim_run_sync(seed=3, waves=3, collect_rl_diag=False):
     tracer = Tracer()
     obs_trace.enable(tracer)
     try:
         srv = fresh_server(seed=seed)
+        srv.collect_rl_diag = collect_rl_diag
         res = EventScheduler(srv, SyncPolicy()).run(waves=waves)
     finally:
         obs_trace.disable()
@@ -366,10 +470,13 @@ def test_ppo_update_metrics_carry_diagnostics():
 
 
 def test_rl_diag_lands_on_round_records_when_traced():
+    """Under tracing with `collect_rl_diag` set, the diagnostics land on
+    the records and as trace counters (tracing alone collects none)."""
     tracer = Tracer()
     obs_trace.enable(tracer)
     try:
         srv = fresh_server()
+        srv.collect_rl_diag = True
         B = srv.allocator.agent.cfg.buffer_size
         srv.pretrain_rl(B + 1)
     finally:
@@ -394,6 +501,170 @@ def test_untraced_rounds_have_no_rl_diag():
     srv = fresh_server()
     srv.run(2)
     assert all(r.rl_diag is None for r in srv.history)
+
+
+# --------------------------------------------------------------------- #
+# planning-layer spans and counts
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def traced_latency_rounds():
+    """`buffer_size + 1` latency-only rounds, traced, against the same
+    rounds untraced: (traced server, its tracer, untraced server)."""
+    obs_trace.disable()
+    plain = fresh_server()
+    B = plain.allocator.agent.cfg.buffer_size
+    for _ in range(B + 1):
+        plain.run_round(latency_only=True)
+    tracer = Tracer()
+    obs_trace.enable(tracer)
+    try:
+        srv = fresh_server()
+        for _ in range(B + 1):
+            srv.run_round(latency_only=True)
+    finally:
+        obs_trace.disable()
+    return srv, tracer, plain
+
+
+@pytest.mark.parametrize("agent", ["ppo1", "ppo2"])
+def test_acts_hold_put_dispatch_and_sync_children(traced_latency_rounds,
+                                                  agent):
+    srv, tracer, _ = traced_latency_rounds
+    acts = _wall_spans(tracer, f"{agent}.act")
+    assert len(acts) == len(srv.history)
+    assert all(a["parent"] == "server.plan_wave" for a in acts)
+    for child in ("ppo.put", "ppo.dispatch", "ppo.sync"):
+        mine = [e for e in _wall_spans(tracer, child)
+                if e["parent"] == f"{agent}.act"]
+        assert len(mine) == len(acts), child
+    # each act's children lie inside it and carry its round
+    for a in acts:
+        kids = [e for e in tracer.events if e.get("parent") == a["name"]
+                and e["args"]["round"] == a["args"]["round"]]
+        assert {k["name"] for k in kids} == {"ppo.put", "ppo.dispatch",
+                                             "ppo.sync"}
+        for k in kids:
+            assert a["ts"] <= k["ts"]
+            assert k["ts"] + k["dur"] <= a["ts"] + a["dur"] + 1e-9
+
+
+def test_one_update_span_per_agent_when_the_buffer_fills(
+        traced_latency_rounds):
+    srv, tracer, _ = traced_latency_rounds
+    for owner, agent in ((srv.allocator, "ppo1"), (srv.intensity, "ppo2")):
+        assert owner.agent.name == agent
+        assert owner.agent.n_updates == 1
+        (upd,) = _wall_spans(tracer, f"{agent}.update")
+        assert upd["parent"] == "server.feedback_wave"
+        kids = {e["name"] for e in tracer.events
+                if e.get("parent") == f"{agent}.update"}
+        assert kids == {"ppo.put", "ppo.dispatch", "ppo.sync"}
+
+
+def test_plan_and_feedback_spans_cover_the_latency_model(
+        traced_latency_rounds):
+    srv, tracer, _ = traced_latency_rounds
+    n = len(srv.history)
+    parent = {"latency.assess": "server.plan_wave",
+              "latency.local_times": "server.plan_wave",
+              "server.split_key": "server.plan_wave",
+              "latency.rewards": None}
+    for name, want in parent.items():
+        spans = _wall_spans(tracer, name)
+        assert len(spans) == (2 * n if name == "latency.rewards" else n)
+        if want is not None:
+            assert {e["parent"] for e in spans} == {want}
+    # the rewards run inside each agent's feedback, under the wave's span
+    assert {e["parent"] for e in _wall_spans(tracer, "latency.rewards")} \
+        == {"server.feedback_wave"}
+
+
+def test_sync_and_launch_counts_match_the_reads_the_code_makes(
+        traced_latency_rounds):
+    srv, tracer, _ = traced_latency_rounds
+    agents = (srv.allocator.agent, srv.intensity.agent)
+    acts = sum(len(_wall_spans(tracer, f"{a.name}.act")) for a in agents)
+    updates = sum(a.n_updates for a in agents)
+    # each act reads its action and its log-probability; each update
+    # reads every metric it returns
+    reads = 2 * acts + sum(a.n_updates * len(a.last_update) for a in agents)
+    assert tracer.counts["ppo.syncs"] == reads
+    assert len(_wall_spans(tracer, "ppo.sync")) == acts + updates
+    # a key split and its unstacking per round, one program per act and
+    # per update
+    rounds = len(srv.history)
+    assert tracer.counts["ppo.launches"] == 2 * rounds + acts + updates
+    assert set(tracer.counts) == {"ppo.syncs", "ppo.launches"}
+
+
+def test_traced_latency_rounds_match_untraced_ones(traced_latency_rounds):
+    import jax
+    srv, _, plain = traced_latency_rounds
+    assert [dataclasses.asdict(r) for r in srv.history] == \
+        [dataclasses.asdict(r) for r in plain.history]
+    assert all(r.rl_diag is None for r in srv.history)
+    for a, b in ((srv.allocator.agent, plain.allocator.agent),
+                 (srv.intensity.agent, plain.intensity.agent)):
+        la, lb = jax.tree_util.tree_leaves(a.params), \
+            jax.tree_util.tree_leaves(b.params)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            assert np.asarray(x).tobytes() == np.asarray(y).tobytes()
+
+
+def test_tracing_alone_runs_no_rl_diagnostics(monkeypatch):
+    import repro.obs.rl as obs_rl
+
+    def refuse(server):
+        raise AssertionError("wave_diagnostics ran without collect_rl_diag")
+    monkeypatch.setattr(obs_rl, "wave_diagnostics", refuse)
+    tracer = Tracer()
+    obs_trace.enable(tracer)
+    try:
+        srv = fresh_server()
+        assert srv.collect_rl_diag is False
+        srv.pretrain_rl(2)
+    finally:
+        obs_trace.disable()
+    assert not [e for e in tracer.events if e["name"].startswith("rl.")]
+    assert all(r.rl_diag is None for r in srv.history)
+
+
+def test_collected_diagnostics_count_their_entropy_forwards():
+    tracer = Tracer()
+    obs_trace.enable(tracer)
+    try:
+        srv = fresh_server()
+        srv.collect_rl_diag = True
+        srv.run_round(latency_only=True)
+    finally:
+        obs_trace.disable()
+    # 2 for the key split, one per act, one entropy forward per agent
+    assert tracer.counts["ppo.launches"] == 2 + 2 + 2
+    assert tracer.counts["ppo.syncs"] == 2 * 2 + 2
+
+
+def test_ppo_programs_compile_under_stable_names():
+    import jax
+    import jax.numpy as jnp
+    from repro.obs.rl import _entropy_fn
+    srv = fresh_server()
+    agent = srv.allocator.agent
+    k = agent.cfg.state_dim
+    state = jnp.zeros((k,), jnp.float32)
+    act = agent._act.lower(agent.params, jax.random.PRNGKey(0), state,
+                           False).as_text()
+    assert "jit_ppo_act" in act.splitlines()[0]
+    B = agent.cfg.buffer_size
+    batch = {"state": jnp.zeros((B, k)), "action": jnp.zeros((B, k),
+                                                             jnp.int32),
+             "logprob": jnp.zeros((B,)), "reward": jnp.zeros((B,))}
+    upd = agent._update.lower(agent.params, agent.opt_state,
+                              batch).as_text()
+    assert "jit_ppo_update" in upd.splitlines()[0]
+    ent = _entropy_fn(agent).lower(agent.params, state).as_text()
+    assert "jit_ppo_entropy" in ent.splitlines()[0]
+    assert "unknown" not in act.splitlines()[0] + upd.splitlines()[0]
 
 
 # --------------------------------------------------------------------- #
