@@ -46,6 +46,13 @@ def _mlp_apply(params, x, final_act=None):
     return final_act(x) if final_act else x
 
 
+#: the metrics `_ppo_update` returns, in the order of its metrics vector
+#: and of `PPOAgent.last_update`'s keys
+UPDATE_METRICS = ("actor_loss", "adv_mean", "adv_std", "approx_kl",
+                  "clip_fraction", "critic_loss", "entropy", "loss",
+                  "mean_ratio", "mean_return", "value_loss")
+
+
 @dataclass
 class PPOConfig:
     state_dim: int                    # k (clients per round)
@@ -68,8 +75,11 @@ class PPOAgent:
     `name` ("ppo1", "ppo2") names the agent's trace spans: `<name>.act`,
     and `<name>.update` when the buffer was full. Each holds the child
     spans `ppo.put` (host->device), `ppo.dispatch` (the jitted call) and
-    `ppo.sync` (the blocking device->host reads); the counts `ppo.launches`
-    and `ppo.syncs` tally programs called and reads made (DESIGN.md §16).
+    `ppo.sync` (the one blocking wait on the device for the call's
+    outputs). The counts `ppo.launches` and `ppo.syncs` tally programs
+    called and arrays copied to the host: two per act (action and
+    log-probability, copied together), one per update (the metrics
+    vector) (DESIGN.md §16).
     """
 
     def __init__(self, cfg: PPOConfig, key, name: str = "ppo"):
@@ -113,7 +123,8 @@ class PPOAgent:
                 action, logprob = self._act(self.params, key, x,
                                             deterministic)
             with tr.span("ppo.sync"):
-                out = np.asarray(action), float(logprob)
+                action, logprob = jax.device_get((action, logprob))
+                out = action, float(logprob)
             tr.count("ppo.launches")
             tr.count("ppo.syncs", 2)
         return out
@@ -140,8 +151,9 @@ class PPOAgent:
             tr.count("ppo.launches")
             self.buffer.clear()
             with tr.span("ppo.sync"):
-                out = {k: float(v) for k, v in metrics.items()}
-            tr.count("ppo.syncs", len(out))
+                vec = np.asarray(metrics)
+            tr.count("ppo.syncs")
+            out = {k: float(v) for k, v in zip(UPDATE_METRICS, vec)}
         self.last_update = out
         self.n_updates += 1
         return out
@@ -261,4 +273,5 @@ def _ppo_update(params, opt_state, batch, *, cfg: PPOConfig):
                "approx_kl": kls[-1], "clip_fraction": clips[-1],
                "entropy": ents[-1], "value_loss": cls[-1],
                "adv_mean": jnp.mean(adv_raw), "adv_std": jnp.std(adv_raw)}
-    return params, opt_state, metrics
+    # one vector, so the host reads an update's metrics in one copy
+    return params, opt_state, jnp.stack([metrics[k] for k in UPDATE_METRICS])

@@ -585,9 +585,9 @@ def test_sync_and_launch_counts_match_the_reads_the_code_makes(
     agents = (srv.allocator.agent, srv.intensity.agent)
     acts = sum(len(_wall_spans(tracer, f"{a.name}.act")) for a in agents)
     updates = sum(a.n_updates for a in agents)
-    # each act reads its action and its log-probability; each update
-    # reads every metric it returns
-    reads = 2 * acts + sum(a.n_updates * len(a.last_update) for a in agents)
+    # each act copies its action and its log-probability to the host,
+    # each update its metrics vector; each call waits once
+    reads = 2 * acts + updates
     assert tracer.counts["ppo.syncs"] == reads
     assert len(_wall_spans(tracer, "ppo.sync")) == acts + updates
     # a key split and its unstacking per round, one program per act and
@@ -595,6 +595,25 @@ def test_sync_and_launch_counts_match_the_reads_the_code_makes(
     rounds = len(srv.history)
     assert tracer.counts["ppo.launches"] == 2 * rounds + acts + updates
     assert set(tracer.counts) == {"ppo.syncs", "ppo.launches"}
+
+
+def test_one_traced_update_waits_once_for_one_array():
+    import jax
+    from repro.core.ppo import PPOAgent, PPOConfig
+    cfg = PPOConfig(state_dim=3, kind="categorical_multihead", buffer_size=2)
+    agent = PPOAgent(cfg, jax.random.PRNGKey(0), name="ppo1")
+    for i in range(2):
+        s = np.full(3, i, np.float32)
+        a, lp = agent.act(jax.random.PRNGKey(i), s)
+        agent.store(s, a, lp, float(i))
+    tracer = Tracer()
+    obs_trace.enable(tracer)
+    try:
+        assert agent.maybe_update() is not None
+    finally:
+        obs_trace.disable()
+    assert len(_wall_spans(tracer, "ppo.sync")) == 1
+    assert tracer.counts == {"ppo.syncs": 1, "ppo.launches": 1}
 
 
 def test_traced_latency_rounds_match_untraced_ones(traced_latency_rounds):

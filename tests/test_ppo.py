@@ -1,10 +1,15 @@
 """PPO agent unit tests: math + learning on toy environments."""
+import hashlib
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.ppo import PPOAgent, PPOConfig, discounted_returns
+from repro.core.ppo import (UPDATE_METRICS, PPOAgent, PPOConfig,
+                            discounted_returns)
 
 
 def test_discounted_returns():
@@ -92,3 +97,66 @@ def test_gaussian_improves_alignment_reward():
         agent.store(s, a, lp, reward)
         agent.maybe_update()
     assert np.mean(rewards[-80:]) > np.mean(rewards[:80])
+
+
+# --------------------------------------------------------------------- #
+# parity: the agents' outputs, pinned bitwise
+# --------------------------------------------------------------------- #
+PARITY = Path(__file__).parent / "data" / "ppo_parity.json"
+
+
+def _parity_run():
+    """12 latency-only rounds on a small server: each agent's buffer fills
+    twice. Returns what each round decided and rewarded, each agent's last
+    update metrics, and a hash of each agent's parameter bytes."""
+    from repro.fl import FLEnvironment, FLSimConfig, HAPFLServer
+    cfg = FLSimConfig(dataset="mnist", n_train=300, n_test=80, n_clients=8,
+                      k_per_round=4, batches_per_epoch=1, default_epochs=2,
+                      batch_size=16)
+    srv = HAPFLServer(FLEnvironment(cfg), seed=3)
+    srv.pretrain_rl(12)
+    out = {"records": [{"sizes": r.sizes, "intensities": r.intensities,
+                        "reward_ppo1": r.reward_ppo1,
+                        "reward_ppo2": r.reward_ppo2} for r in srv.history],
+           "agents": {}}
+    for agent in (srv.allocator.agent, srv.intensity.agent):
+        h = hashlib.sha256()
+        for leaf in jax.tree_util.tree_leaves(agent.params):
+            h.update(np.asarray(leaf).tobytes())
+        out["agents"][agent.name] = {"n_updates": agent.n_updates,
+                                     "last_update": agent.last_update,
+                                     "params_sha256": h.hexdigest()}
+    return out
+
+
+def test_agents_reproduce_the_pinned_rounds_bitwise():
+    """Same programs on the same inputs: every decision, reward, update
+    metric and parameter byte matches the pinned run."""
+    want = json.loads(PARITY.read_text())
+    got = json.loads(json.dumps(_parity_run()))
+    assert got["records"] == want["records"]
+    for name, agent in want["agents"].items():
+        assert got["agents"][name]["n_updates"] == agent["n_updates"] == 2
+        # the dict's key order too
+        assert list(got["agents"][name]["last_update"].items()) == \
+            list(agent["last_update"].items())
+        assert got["agents"][name]["params_sha256"] == agent["params_sha256"]
+
+
+def test_update_metrics_come_back_in_their_declared_order():
+    cfg = PPOConfig(state_dim=3, kind="gaussian_simplex", buffer_size=2)
+    agent = PPOAgent(cfg, jax.random.PRNGKey(0))
+    for i in range(2):
+        s = np.full(3, i, np.float32)
+        a, lp = agent.act(jax.random.PRNGKey(i), s)
+        agent.store(s, a, lp, float(i))
+    metrics = agent.maybe_update()
+    assert tuple(metrics) == UPDATE_METRICS
+    assert metrics is agent.last_update
+    assert all(type(v) is float for v in metrics.values())
+    assert metrics["value_loss"] == metrics["critic_loss"]
+
+
+if __name__ == "__main__":
+    # writes the fixture; run from the repo root with PYTHONPATH=src
+    PARITY.write_text(json.dumps(_parity_run(), indent=1) + "\n")
