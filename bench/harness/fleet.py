@@ -21,7 +21,6 @@ import jax
 import numpy as np
 
 from bench.harness import spec
-from bench.references import cnn as ref_cnn
 
 
 _T0 = time.perf_counter()
@@ -41,32 +40,18 @@ def derive(seed: int, stream: int) -> int:
                .generate_state(1)[0] & 0x7FFFFFFF)
 
 
-def sim_config(config: Dict, traffic: Dict, fleet_seed: int):
-    from repro.fl import FLSimConfig
-    return FLSimConfig(
-        dataset=config["dataset"], n_clients=traffic["n_clients"],
-        k_per_round=traffic["k_per_round"],
-        max_speed_ratio=traffic["max_speed_ratio"],
-        size_names=tuple(config["size_names"]),
-        default_epochs=traffic["default_epochs"],
-        batch_size=config["batch_size"],
-        batches_per_epoch=config["batches_per_epoch"], lr=config["lr"],
-        dirichlet_alpha=traffic["dirichlet_alpha"],
-        n_train=traffic["n_train"], n_test=traffic["n_test"],
-        seed=fleet_seed)
-
-
 def build(config: Dict, traffic: Dict, seed: int, shadow: bool = True):
     """(env, server, shadow_env, server seed) for one run. `shadow_env` is
     an untouched copy of the environment for look-ahead planning (None
     without `shadow`)."""
     from repro.fl import FLEnvironment, HAPFLServer
-    spec.check_config(config)
+    fam = spec.family(config)
+    fam.check_config(config)
     fleet = traffic.get("fleet_seed")
     fleet_seed = derive(seed, 0) if fleet is None else int(fleet)
     server_seed = derive(seed, 1) if fleet is None else int(fleet)
-    env = FLEnvironment(sim_config(config, traffic, fleet_seed))
-    spec.check_data(config, env.data)
+    env = FLEnvironment(fam.sim_config(config, traffic, fleet_seed))
+    fam.check_data(config, env.data)
     shadow_env = copy.deepcopy(env) if shadow else None
     srv = HAPFLServer(env, seed=server_seed)
     if srv.engine != "batched":
@@ -79,12 +64,12 @@ def seeded_weights(config: Dict, seed: int) -> Dict:
     """The LiteModel and every size's global model, drawn from the seed on
     the device in one jitted call."""
     names = ["lite"] + list(config["size_names"])
-    from bench.harness.flops import widths
+    fam = spec.family(config)
 
     @jax.jit
     def init(key):
         keys = jax.random.split(key, len(names))
-        return {n: ref_cnn.init_params(k, widths(config, n))
+        return {n: fam.init_params(k, config, n)
                 for n, k in zip(names, keys)}
     key = np.random.SeedSequence([seed, 2]).generate_state(2, np.uint32)
     return init(jax.numpy.asarray(key))

@@ -9,6 +9,12 @@ adding files:
     bench/traffic/<traffic>.json    fleet and round mix
     bench/limits/<cell>.json        limits of the numbers `correct` compares
     bench/metrics/<metric>.py       reader of one per-layer metric
+    the file the config's `reference`
+    names (a path from the root)    the model family: its plain reference
+                                    and the functions of FAMILY_API below
+
+No module of the harness names a model family; a new client family is a
+new configuration and a new reference module.
 """
 from __future__ import annotations
 
@@ -16,10 +22,29 @@ import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
+
+
+# What a model family's module exports (bench/references/cnn.py is one):
+#   check_config(config)                refuse a program whose model differs
+#                                       from the config (ValueError)
+#   check_data(config, data)            refuse data of another shape or dtype
+#   sim_config(config, traffic, fleet_seed)   the program's FLSimConfig
+#   init_params(key, config, size)      one model's initial weights (jittable)
+#   train_client(start, xs, ys, config, dtype, keep)   the reference's local
+#                                       training: (trained, first gradient)
+#   entropy(labels, n_classes), accuracy(params, x, y, dtype),
+#   aggregate(lite, by_size, sizes, trained, entropies, accs_local,
+#             accs_lite)                the reference's Eqs. 36-39 and 5
+#   step_flops(config, size), step_bytes(config, size)   least operations
+#                                       and bytes of one client step
+FAMILY_API = ("check_config", "check_data", "sim_config", "init_params",
+              "train_client", "entropy", "accuracy", "aggregate",
+              "step_flops", "step_bytes")
 
 
 class SpecError(ValueError):
@@ -48,13 +73,39 @@ def load_benchmark(root: Path = ROOT) -> Dict:
     return _read_json(root / "BENCHMARK.json")
 
 
-def load_reader(path: Path) -> Callable:
-    """A per-layer metric's reader: the `read(ctx)` of its own file."""
+def _load_module(path: Path, prefix: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + path.stem.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(path: Path) -> Callable:
+    """A per-layer metric's reader: the `read(ctx)` of its own file."""
+    return _load_module(path, "bench_metric_").read
+
+
+_FAMILIES: Dict[Path, ModuleType] = {}
+
+
+def family(config: Dict, root: Path = ROOT) -> ModuleType:
+    """The model family of a configuration: the module at its `reference`
+    path, loaded once per path. Refused where the file is missing or lacks
+    a function of FAMILY_API."""
+    path = (root / config["reference"]).resolve()
+    if path not in _FAMILIES:
+        if not path.is_file():
+            raise SpecError(f"config {config.get('name')!r} names reference "
+                            f"{config['reference']!r}, which is no file")
+        mod = _load_module(path, "bench_family_")
+        missing = [f for f in FAMILY_API
+                   if not callable(getattr(mod, f, None))]
+        if missing:
+            raise SpecError(f"{config['reference']} lacks "
+                            f"{', '.join(missing)}")
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -86,34 +137,3 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, limits=limits, end_to_end=e2e,
                 per_layer=per_layer, readers=readers)
-
-
-def check_config(config: Dict) -> None:
-    """Refuse to measure a model other than the one the config states: the
-    program's pool must have the stated widths for every size."""
-    from repro.models.cnn import cnn_pool
-    pool = cnn_pool(config["dataset"])
-    for size, want in config["pool"].items():
-        if size not in pool:
-            raise SpecError(f"cnn_pool({config['dataset']!r}) has no size "
-                            f"{size!r}")
-        got = pool[size]
-        have = {"in_shape": list(got.in_shape), "channels": list(got.channels),
-                "hidden": got.hidden, "n_classes": got.n_classes}
-        stated = {"in_shape": config["in_shape"],
-                  "channels": want["channels"], "hidden": want["hidden"],
-                  "n_classes": config["n_classes"]}
-        if have != stated:
-            raise SpecError(f"cnn_pool({config['dataset']!r})[{size!r}] is "
-                            f"{have}, the config states {stated}")
-
-
-def check_data(config: Dict, data: Dict) -> None:
-    """The environment's images must have the stated shape and dtype."""
-    for split in ("x_train", "x_test"):
-        x = data[split]
-        if list(x.shape[1:]) != config["in_shape"] or \
-                str(x.dtype) != config["input_dtype"]:
-            raise SpecError(f"{split} is {x.dtype}{list(x.shape[1:])}, the "
-                            f"config states {config['input_dtype']}"
-                            f"{config['in_shape']}")

@@ -7,8 +7,8 @@ drives the first training round through `run_round`, keeping what it read
 and produced for the check. The window then runs `run_round` until its
 time is up; each round ends with the globals on the device.
 
-The check replays that first round with the plain float32 reference
-(bench/references/cnn.py): every client from the same initial globals on
+The check replays that first round with the plain float32 reference of
+the config's model family (the module its `reference` names): every client from the same initial globals on
 the same batches, the aggregation of the reference's clients, and, where
 the round evaluates, the reference's accuracies.
 """
@@ -21,10 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness import compare, device, fleet
+from bench.harness import compare, device, fleet, spec
 from bench.harness import trace as trace_mod
-from bench.harness.flops import step_bytes, step_flops
-from bench.references import cnn as ref_cnn
 
 
 class Capture:
@@ -135,6 +133,7 @@ class TrainingRun:
         """Rounds until `seconds` have passed (the last one counts whole),
         or the traffic's `trace_rounds` under the profiler."""
         srv, config = self.srv, self.config
+        fam = spec.family(config)
         max_rounds = (int(self.traffic["trace_rounds"]) if trace_dir
                       else int(self.traffic["plan_horizon"]))
         self.failed = 0
@@ -172,8 +171,10 @@ class TrainingRun:
                 "wrappers": dict(self.probe.seconds),
                 "compiles": meter.since(snap)["compiles"],
                 "real_steps": sum(n for _, n in work),
-                "real_flops": sum(n * step_flops(config, s) for s, n in work),
-                "real_bytes": sum(n * step_bytes(config, s) for s, n in work),
+                "real_flops": sum(n * fam.step_flops(config, s)
+                                  for s, n in work),
+                "real_bytes": sum(n * fam.step_bytes(config, s)
+                                  for s, n in work),
                 "shapes_warmed": len(self.shapes)}}
 
     def release(self) -> None:
@@ -209,33 +210,33 @@ def reference_round(config, traffic, data, w0, batches, plan,
     """The reference's first round: trained clients, accuracies and the
     aggregated globals, from the initial globals and the same batches.
     `keep` uses only that many rows of each batch (a planted fault)."""
+    fam = spec.family(config)
     clients, sizes, _ = plan
     trained, grads = [], []
     for c, s in zip(clients, sizes):
         xs, ys = batches[int(c)]
-        p, g = ref_cnn.train_client({"local": w0[s], "lite": w0["lite"]},
-                                    xs, ys, config["lr"],
-                                    config["momentum"], dtype, keep)
+        p, g = fam.train_client({"local": w0[s], "lite": w0["lite"]},
+                                xs, ys, config, dtype, keep)
         trained.append(p)
         grads.append(g)
     x_tr, y_tr, parts = data["x_train"], data["y_train"], data["parts"]
-    ents = [ref_cnn.entropy(y_tr[parts[c]], config["n_classes"])
+    ents = [fam.entropy(y_tr[parts[c]], config["n_classes"])
             for c in clients]
     accs = {}
     if traffic["eval_accuracy"]:
         for c, p in zip(clients, trained):
             for which in ("local", "lite"):
-                accs[("client", which, int(c))] = ref_cnn.accuracy(
+                accs[("client", which, int(c))] = fam.accuracy(
                     p[which], x_tr[parts[c]], y_tr[parts[c]], dtype)
     acc_loc = [accs.get(("client", "local", int(c)), 0.0) for c in clients]
     acc_lite = [accs.get(("client", "lite", int(c)), 0.0) for c in clients]
-    lite, by_size = ref_cnn.aggregate(
+    lite, by_size = fam.aggregate(
         w0["lite"], {s: w0[s] for s in config["size_names"]}, sizes,
         trained, ents, acc_loc, acc_lite)
     after = {"lite": lite, **by_size}
     if traffic["eval_accuracy"]:
         for name, p in after.items():
-            accs[("global", name)] = ref_cnn.accuracy(
+            accs[("global", name)] = fam.accuracy(
                 p, data["x_test"], data["y_test"], dtype)
     return {"trained": trained, "grads": grads, "after": after, "accs": accs}
 

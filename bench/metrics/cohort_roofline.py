@@ -1,7 +1,8 @@
 """Cohort programs: the least time the chip could take for the real client
 steps (the larger of their operations over peak FLOP/s and their bytes
-over peak HBM bandwidth, bench/harness/flops.py) over the device time of
-the cohort programs (jit_train_one) in the trace, in %."""
+over peak HBM bandwidth, from the model family's step_flops and
+step_bytes) over the device time of the cohort programs (jit_train_one)
+in the trace, in %."""
 
 PROGRAM = "jit_train_one"
 

@@ -1,6 +1,12 @@
-"""Plain reference of one HAPFL training round for the CNN pool.
+"""The CNN client family: the plain reference of one HAPFL training round
+for the CNN pool, and what the benchmark needs to know of the family.
 
-Follows the paper (arXiv:2501.16966) and imports nothing of src/repro:
+A configuration names its family by the file in its `reference` key; the
+harness loads that file (`bench/harness/spec.family`) and calls only the
+functions in `spec.FAMILY_API`, so one family is one file.
+
+The reference follows the paper (arXiv:2501.16966) and imports nothing of
+src/repro:
 
   forward       3x3 SAME conv + ReLU + 2x2/2 max-pool per stage, flatten
                 (NHWC row-major), fc1 + ReLU, fc2 (§V.A's CNN pool); each
@@ -20,6 +26,12 @@ Follows the paper (arXiv:2501.16966) and imports nothing of src/repro:
 
 `dtype` is the precision the whole computation runs in: float32 at
 "highest" matmul precision is the reference; bfloat16 is the control.
+
+The operation and byte counts of a client step (`step_flops`,
+`step_bytes`) come from the config's widths, never from the program's.
+Only the glue at the end (`check_config`, `check_data`, `sim_config`)
+touches the program: it checks the program's pool and data against the
+config and builds the program's simulation config.
 """
 from __future__ import annotations
 
@@ -34,13 +46,25 @@ import numpy as np
 LAMBDAS = (0.4, 0.6, 0.5, 0.5)    # paper Table II
 
 
+F32 = 4
+
+
+def widths(config: Dict, size: str) -> Dict:
+    """The stated widths of one model of the pool (`lite` or a size)."""
+    w = config["pool"][size]
+    return {"in_shape": config["in_shape"], "channels": w["channels"],
+            "hidden": w["hidden"], "n_classes": config["n_classes"]}
+
+
 def _precision(dtype):
     return "highest" if jnp.dtype(dtype) == jnp.float32 else None
 
 
-def init_params(key, w: Dict):
-    """He-normal weights, zero biases, in the layout the server keeps:
-    {conv: [...], conv_b: [...], fc1, fc1_b, fc2, fc2_b}."""
+def init_params(key, config: Dict, size: str):
+    """He-normal weights, zero biases, of the config's model `size`, in the
+    layout the server keeps: {conv: [...], conv_b: [...], fc1, fc1_b, fc2,
+    fc2_b}."""
+    w = widths(config, size)
     c_in = w["in_shape"][2]
     keys = jax.random.split(key, len(w["channels"]) + 2)
     p = {"conv": [], "conv_b": []}
@@ -123,9 +147,10 @@ def client_step(params, mu, x, y, *, lr, momentum, dtype):
     return params, mu, g
 
 
-def train_client(start, xs, ys, lr: float, momentum: float,
-                 dtype=jnp.float32, keep=None):
-    """Train {local, lite} from `start` over the batches xs[i], ys[i].
+def train_client(start, xs, ys, config: Dict, dtype=jnp.float32,
+                 keep=None):
+    """Train {local, lite} from `start` over the batches xs[i], ys[i], at
+    the config's `lr` and `momentum`.
 
     `keep`, where given, is the number of rows of each batch to use (a
     control that leaves part of the batch out). Returns the trained params
@@ -137,7 +162,8 @@ def train_client(start, xs, ys, lr: float, momentum: float,
         if keep is not None:
             x, y = x[:keep], y[:keep]
         p, mu, g = client_step(p, mu, jnp.asarray(x), jnp.asarray(y),
-                               lr=lr, momentum=momentum, dtype=dtype)
+                               lr=config["lr"], momentum=config["momentum"],
+                               dtype=dtype)
         if g0 is None:
             g0 = g
     to_np = functools.partial(jax.tree_util.tree_map,
@@ -204,3 +230,108 @@ def aggregate(lite, by_size: Dict, sizes: Sequence[str], trained: List,
                       weights([entropies[i] for i in idx],
                               [accs_local[i] for i in idx]))
     return new_lite, new
+
+
+# ---------------------------------------------------------------------- #
+# operations and bytes of one client step
+# ---------------------------------------------------------------------- #
+# A client step trains the local model and the LiteModel on one batch
+# (Eqs. 33-35): a forward and a backward pass through each. A matmul-like
+# layer costs 2*MACs forward and twice that backward (input and weight
+# gradients), except the first layer, whose input needs no gradient.
+# Elementwise work (ReLU, pooling, softmax, the optimizer) is not counted:
+# it is a few percent and not what the chip's peak is quoted for.
+def layer_flops(w: Dict) -> List[int]:
+    """Forward operations per sample of each layer."""
+    h, wd, c = w["in_shape"]
+    out = []
+    for co in w["channels"]:
+        out.append(2 * h * wd * 9 * c * co)
+        c, h, wd = co, h // 2, wd // 2
+    flat = max(h, 1) * max(wd, 1) * c
+    out += [2 * flat * w["hidden"], 2 * w["hidden"] * w["n_classes"]]
+    return out
+
+
+def param_count(w: Dict) -> int:
+    h, wd, c = w["in_shape"]
+    n = 0
+    for co in w["channels"]:
+        n += 9 * c * co + co
+        c, h, wd = co, h // 2, wd // 2
+    flat = max(h, 1) * max(wd, 1) * c
+    return n + flat * w["hidden"] + w["hidden"] + \
+        w["hidden"] * w["n_classes"] + w["n_classes"]
+
+
+def train_flops_per_sample(w: Dict) -> int:
+    f = layer_flops(w)
+    return 3 * sum(f) - f[0]
+
+
+def step_flops(config: Dict, size: str) -> int:
+    """One client step of `size` + LiteModel at the config's batch."""
+    return config["batch_size"] * (
+        train_flops_per_sample(widths(config, size))
+        + train_flops_per_sample(widths(config, "lite")))
+
+
+def step_bytes(config: Dict, size: str) -> int:
+    """Bytes a step has to move at the least: the batch in, and the
+    parameters and momentum of both models read and written once."""
+    h, wd, c = config["in_shape"]
+    params = param_count(widths(config, size)) + \
+        param_count(widths(config, "lite"))
+    batch = config["batch_size"] * (h * wd * c * F32 + 4)
+    return batch + 4 * params * F32
+
+
+# ---------------------------------------------------------------------- #
+# glue to the program under test
+# ---------------------------------------------------------------------- #
+def check_config(config: Dict) -> None:
+    """Refuse to measure a model other than the one the config states: the
+    program's pool must have the stated widths for every size."""
+    from repro.models.cnn import cnn_pool
+    pool = cnn_pool(config["dataset"])
+    for size, want in config["pool"].items():
+        if size not in pool:
+            raise ValueError(f"cnn_pool({config['dataset']!r}) has no "
+                             f"size {size!r}")
+        got = pool[size]
+        have = {"in_shape": list(got.in_shape), "channels": list(got.channels),
+                "hidden": got.hidden, "n_classes": got.n_classes}
+        stated = {"in_shape": config["in_shape"],
+                  "channels": want["channels"], "hidden": want["hidden"],
+                  "n_classes": config["n_classes"]}
+        if have != stated:
+            raise ValueError(f"cnn_pool({config['dataset']!r})[{size!r}]"
+                             f" is {have}, the config states {stated}")
+
+
+def check_data(config: Dict, data: Dict) -> None:
+    """The environment's images must have the stated shape and dtype."""
+    for split in ("x_train", "x_test"):
+        x = data[split]
+        if list(x.shape[1:]) != config["in_shape"] or \
+                str(x.dtype) != config["input_dtype"]:
+            raise ValueError(f"{split} is {x.dtype}{list(x.shape[1:])}, the "
+                             f"config states {config['input_dtype']}"
+                             f"{config['in_shape']}")
+
+
+def sim_config(config: Dict, traffic: Dict, fleet_seed: int):
+    """The program's simulation config for the config's pool and a traffic
+    mix, its fleet drawn from `fleet_seed`."""
+    from repro.fl import FLSimConfig
+    return FLSimConfig(
+        dataset=config["dataset"], n_clients=traffic["n_clients"],
+        k_per_round=traffic["k_per_round"],
+        max_speed_ratio=traffic["max_speed_ratio"],
+        size_names=tuple(config["size_names"]),
+        default_epochs=traffic["default_epochs"],
+        batch_size=config["batch_size"],
+        batches_per_epoch=config["batches_per_epoch"], lr=config["lr"],
+        dirichlet_alpha=traffic["dirichlet_alpha"],
+        n_train=traffic["n_train"], n_test=traffic["n_test"],
+        seed=fleet_seed)

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.harness.flops import widths
 from bench.references import cnn as ref_cnn
 from bench.references import ppo as ref_ppo
 
@@ -30,7 +29,7 @@ def _close(a, b, tol):
 def test_forward_matches_apply_cnn(size):
     from repro.models.cnn import apply_cnn, cnn_pool
     cfg = cnn_pool("mnist")[size]
-    p = ref_cnn.init_params(jax.random.PRNGKey(3), widths(CONFIG, size))
+    p = ref_cnn.init_params(jax.random.PRNGKey(3), CONFIG, size)
     x = jax.random.normal(jax.random.PRNGKey(4), (5, 28, 28, 1))
     _close(ref_cnn.forward(p, x), apply_cnn(p, cfg, x), 1e-4)
 
@@ -39,7 +38,7 @@ def test_init_layout_matches_init_cnn():
     from repro.models.cnn import cnn_pool, init_cnn
     key = jax.random.PRNGKey(7)
     for size in ("lite", "small", "large"):
-        mine = ref_cnn.init_params(key, widths(CONFIG, size))
+        mine = ref_cnn.init_params(key, CONFIG, size)
         theirs = init_cnn(key, cnn_pool("mnist")[size])
         assert jax.tree_util.tree_structure(mine) == \
             jax.tree_util.tree_structure(theirs)
@@ -54,17 +53,18 @@ def test_client_training_matches_the_mutual_kd_step():
         functools.partial(lambda p, x, c: apply_cnn(p, c, x), c=pool["small"]),
         functools.partial(lambda p, x, c: apply_cnn(p, c, x), c=pool["lite"]),
         lr=5e-3)
-    start = {"local": ref_cnn.init_params(jax.random.PRNGKey(0),
-                                          widths(CONFIG, "small")),
-             "lite": ref_cnn.init_params(jax.random.PRNGKey(1),
-                                         widths(CONFIG, "lite"))}
+    start = {"local": ref_cnn.init_params(jax.random.PRNGKey(0), CONFIG,
+                                          "small"),
+             "lite": ref_cnn.init_params(jax.random.PRNGKey(1), CONFIG,
+                                         "lite")}
     rng = np.random.default_rng(0)
     xs = rng.normal(size=(3, 8, 28, 28, 1)).astype(np.float32)
     ys = rng.integers(0, 10, size=(3, 8)).astype(np.int32)
     p, o = start, init_opt(start)
     for x, y in zip(xs, ys):
         p, o, _ = step(p, o, x, y)
-    mine, _ = ref_cnn.train_client(start, xs, ys, 5e-3, 0.9)
+    mine, _ = ref_cnn.train_client(start, xs, ys,
+                                   {"lr": 5e-3, "momentum": 0.9})
     _close(mine, p, 1e-5)
 
 
@@ -79,8 +79,8 @@ def test_entropy_and_fold_match_the_server_aggregation():
     np.testing.assert_allclose(ref_cnn.weights(ents, accs),
                                aggregation_weights(ents, accs))
     keys = jax.random.split(jax.random.PRNGKey(2), 4)
-    g = ref_cnn.init_params(keys[0], widths(CONFIG, "small"))
-    cs = [ref_cnn.init_params(k, widths(CONFIG, "small")) for k in keys[1:]]
+    g = ref_cnn.init_params(keys[0], CONFIG, "small")
+    cs = [ref_cnn.init_params(k, CONFIG, "small") for k in keys[1:]]
     w = ref_cnn.weights(ents, accs)
     _close(ref_cnn.fold(g, cs, w), weighted_aggregate(g, cs, w), 1e-5)
     sizes = ["small", "small", "small"]

@@ -1,5 +1,6 @@
-"""Cell lookup, configuration checks, the FLOP counter and the harness's
-refusal to run without a TPU."""
+"""Cell lookup, the model family a configuration names, configuration
+checks, the FLOP counter and the harness's refusal to run without a
+TPU."""
 import json
 import os
 import shutil
@@ -10,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench.harness import device, flops, spec
+from bench.harness import device, spec
+from bench.tests import checks
 
 CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 
@@ -18,7 +20,7 @@ CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_loads_with_its_readers(name):
     cell = spec.load_cell(name)
-    spec.check_config(cell.config)
+    spec.family(cell.config).check_config(cell.config)
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert cell.per_layer and set(cell.readers) == {m["name"]
                                                     for m in cell.per_layer}
@@ -29,8 +31,8 @@ def test_a_width_that_differs_from_the_program_is_refused():
     cell = spec.load_cell(CELLS[0])
     cfg = json.loads(json.dumps(cell.config))
     cfg["pool"]["large"]["hidden"] += 1
-    with pytest.raises(spec.SpecError, match="hidden"):
-        spec.check_config(cfg)
+    with pytest.raises(ValueError, match="hidden"):
+        spec.family(cfg).check_config(cfg)
 
 
 def test_data_of_another_shape_is_refused():
@@ -39,8 +41,8 @@ def test_data_of_another_shape_is_refused():
     shape = [2] + cell.config["in_shape"][:-1] + [7]
     bad = {"x_train": np.zeros(shape, np.float32),
            "x_test": np.zeros(shape, np.float32)}
-    with pytest.raises(spec.SpecError):
-        spec.check_data(cell.config, bad)
+    with pytest.raises(ValueError):
+        spec.family(cell.config).check_data(cell.config, bad)
 
 
 def test_a_cell_of_new_files_only_is_found_by_name(tmp_path):
@@ -79,6 +81,57 @@ def test_a_cell_of_new_files_only_is_found_by_name(tmp_path):
         spec.load_cell("no.such.cell", root=tmp_path)
 
 
+def test_family_is_the_module_the_config_names():
+    cell = spec.load_cell(CELLS[0])
+    fam = spec.family(cell.config)
+    assert fam.__file__ == str(spec.ROOT / cell.config["reference"])
+    assert spec.family(dict(cell.config)) is fam
+    assert all(callable(getattr(fam, f)) for f in spec.FAMILY_API)
+
+
+def test_a_family_that_lacks_a_function_is_refused(tmp_path):
+    src = (spec.BENCH / "references" / "cnn.py").read_text()
+    for gone in ("step_bytes", "aggregate"):
+        src = src.replace(f"def {gone}(", f"def _{gone}(")
+    (tmp_path / "partial.py").write_text(src)
+    cfg = dict(spec.load_cell(CELLS[0]).config, reference="partial.py")
+    with pytest.raises(spec.SpecError, match="aggregate, step_bytes"):
+        spec.family(cfg, root=tmp_path)
+    with pytest.raises(spec.SpecError, match="no file"):
+        spec.family(dict(cfg, reference="absent.py"), root=tmp_path)
+
+
+def test_a_training_run_takes_the_model_from_the_family(monkeypatch):
+    """The harness trains and seeds through the functions of the module the
+    config names; wrapped copies there see every call."""
+    from bench.harness import compare, train_cell
+    cell = checks.file_cell("cnn-mnist", "fleet_k64", "mnist.fleet_k64",
+                            checks.TRAIN)
+    fam = spec.family(cell.config)
+    calls = {"train_client": 0, "init_params": []}
+    train, init = fam.train_client, fam.init_params
+
+    def train_client(*args, **kw):
+        calls["train_client"] += 1
+        return train(*args, **kw)
+
+    def init_params(key, config, size):
+        calls["init_params"].append(size)
+        return init(key, config, size)
+    monkeypatch.setattr(fam, "train_client", train_client)
+    monkeypatch.setattr(fam, "init_params", init_params)
+    run = train_cell.TrainingRun(cell, 5)
+    run.setup(warm=False)
+    prog = run.program_outputs()
+    run.release()
+    verdict = compare.verdict(run.numbers(prog, run.reference()),
+                              cell.limits["limits"])
+    assert verdict["correct"]
+    assert calls["train_client"] == cell.traffic["k_per_round"]
+    assert sorted(calls["init_params"]) == sorted(
+        ["lite"] + cell.config["size_names"])
+
+
 def test_peak_table_refuses_an_unknown_kind():
     assert device.peaks("TPU v5 lite")["flops_bf16"] == 197e12
     with pytest.raises(KeyError):
@@ -104,7 +157,7 @@ def test_flop_counter_against_cost_analysis(config, size):
     y = jnp.zeros((cfg["batch_size"],), jnp.int32)
     xla = jax.jit(step).lower(p, init_opt(p), x, y).compile() \
         .cost_analysis()["flops"]
-    assert 0.85 <= flops.step_flops(cfg, size) / xla <= 1.0
+    assert 0.85 <= spec.family(cfg).step_flops(cfg, size) / xla <= 1.0
 
 
 def test_harness_exits_nonzero_without_a_tpu():
