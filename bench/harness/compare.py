@@ -9,7 +9,7 @@ round-off alone and are left out.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 import jax
 import numpy as np
@@ -17,31 +17,38 @@ import numpy as np
 GRAD_FLOOR = 1e-3
 
 
-def _leaves(tree) -> List[np.ndarray]:
-    return [np.asarray(x, np.float64) for x in jax.tree_util.tree_leaves(tree)]
+def norms(tree) -> np.ndarray:
+    """Per leaf, its norm in float64."""
+    return np.array([np.linalg.norm(np.asarray(x, np.float64))
+                     for x in jax.tree_util.tree_leaves(tree)])
 
 
-def change_gaps(prog, ref, base, grad=None) -> np.ndarray:
+def change_gaps(prog, ref, base, grad_norms=None) -> np.ndarray:
     """Per leaf, |‖prog-base‖ - ‖ref-base‖| / max(‖ref-base‖, median leaf's
-    ‖ref-base‖); NaN for a leaf left out."""
-    p, r, b = _leaves(prog), _leaves(ref), _leaves(base)
-    dp = np.array([np.linalg.norm(x - y) for x, y in zip(p, b)])
-    dr = np.array([np.linalg.norm(x - y) for x, y in zip(r, b)])
+    ‖ref-base‖); NaN for a leaf left out by `grad_norms`, the per-leaf
+    norms of the reference's gradient. One leaf at a time in float64."""
+    leaves = jax.tree_util.tree_leaves
+    dp, dr = [], []
+    for p, r, b in zip(leaves(prog), leaves(ref), leaves(base)):
+        b = np.asarray(b, np.float64)
+        dp.append(np.linalg.norm(np.asarray(p, np.float64) - b))
+        dr.append(np.linalg.norm(np.asarray(r, np.float64) - b))
+    dp, dr = np.array(dp), np.array(dr)
     keep = np.ones(len(dr), bool)
-    if grad is not None:
-        gn = np.array([np.linalg.norm(g) for g in _leaves(grad)])
+    if grad_norms is not None:
+        gn = np.asarray(grad_norms)
         keep = gn >= GRAD_FLOOR * np.median(gn)
     scale = np.maximum(dr, np.median(dr[keep]))
     return np.where(keep, np.abs(dp - dr) / scale, np.nan)
 
 
-def change_gap(prog, ref, base, grad=None) -> float:
+def change_gap(prog, ref, base, grad_norms=None) -> float:
     """The worst leaf's gap."""
-    return float(np.nanmax(change_gaps(prog, ref, base, grad)))
+    return float(np.nanmax(change_gaps(prog, ref, base, grad_norms)))
 
 
 def finite(tree) -> bool:
-    return all(np.isfinite(x).all() for x in _leaves(tree))
+    return all(np.isfinite(x).all() for x in jax.tree_util.tree_leaves(tree))
 
 
 def verdict(numbers: Dict[str, Optional[float]], limits: Dict) -> Dict:

@@ -1,9 +1,10 @@
 """The chip: its peaks, the compile cache, compile counting, memory."""
 from __future__ import annotations
 
+import contextlib
 from pathlib import Path
 
-from bench.harness.spec import ROOT
+from bench.harness.spec import REFERENCE_DEVICES, ROOT
 
 # Published peaks per chip, keyed by `device_kind` as JAX reports it.
 # Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
@@ -51,15 +52,29 @@ def require_accelerator(chips: int):
     return devs[:chips]
 
 
-def reference_device(dtype):
-    """Where a reference runs: float32 on the host's CPU, exact and quick
-    to compile (the TPU compiler takes minutes and tens of GB for a float32
-    step at "highest" precision); a lower-precision control on the chip."""
+@contextlib.contextmanager
+def reference_device(dtype, where: str = "host"):
+    """Where a reference runs in `dtype`. Float32 runs on the host's CPU
+    (`where="host"`: exact, and quick to compile where the TPU compiler
+    takes minutes and tens of GB for a float32 convolution at "highest"
+    precision) or on the chip at "highest" matmul precision
+    (`where="chip"`: for a family too large for the host); a
+    lower-precision control runs on the chip at the default precision."""
     import jax
     import jax.numpy as jnp
-    return jax.default_device(jax.devices("cpu")[0]
-                              if jnp.dtype(dtype) == jnp.float32
-                              else jax.devices()[0])
+    if where not in REFERENCE_DEVICES:
+        raise ValueError(f"reference device {where!r}; known: "
+                         f"{REFERENCE_DEVICES}")
+    if jnp.dtype(dtype) != jnp.float32:
+        with jax.default_device(jax.devices()[0]):
+            yield
+    elif where == "host":
+        with jax.default_device(jax.devices("cpu")[0]):
+            yield
+    else:
+        with jax.default_device(jax.devices()[0]), \
+                jax.default_matmul_precision("highest"):
+            yield
 
 
 def device_info(devs) -> dict:

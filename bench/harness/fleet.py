@@ -13,6 +13,7 @@ import copy
 import functools
 import resource
 import sys
+import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Sequence, Tuple
@@ -32,6 +33,44 @@ def note(msg: str) -> None:
     peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2 ** 20
     print(f"[bench {time.perf_counter() - _T0:7.1f}s peak {peak:5.1f}GiB] "
           f"{msg}", file=sys.stderr, flush=True)
+
+
+def host_gib() -> float:
+    """The process's resident memory now less its file-backed pages where
+    the kernel counts them apart, in GiB (/proc/self/statm; 0 where there
+    is none)."""
+    try:
+        with open("/proc/self/statm") as f:
+            _, resident, shared = (int(v) for v in f.read().split()[:3])
+    except (OSError, ValueError):
+        return 0.0
+    return (resident - shared) * resource.getpagesize() / 2 ** 30
+
+
+class HostMemory:
+    """`host_gib()` when the block starts (`start`) and the highest reading
+    while it runs (`peak`), sampled every `every` seconds on a thread of
+    its own, which ends with the block."""
+
+    def __init__(self, every: float = 0.05):
+        self.every = every
+        self.start = self.peak = 0.0
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._sample, daemon=True)
+
+    def _sample(self) -> None:
+        while not self._stop.wait(self.every):
+            self.peak = max(self.peak, host_gib())
+
+    def __enter__(self) -> "HostMemory":
+        self.start = self.peak = host_gib()
+        self._thread.start()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._stop.set()
+        self._thread.join()
+        self.peak = max(self.peak, host_gib())
 
 
 def derive(seed: int, stream: int) -> int:

@@ -58,8 +58,12 @@ def execute(cell, seed: int, seconds: float, traced: bool, t_start: float,
         res = run.window(seconds, meter, trace_dir)
         mem = device.memory_peak_bytes(devs)
         run.release()
-        numbers = run.numbers(run.program_outputs(), run.reference())
-        fleet.note("check done")
+        t_check = time.perf_counter()
+        with fleet.HostMemory() as host:
+            numbers = run.check()
+        fleet.note(f"check done in {time.perf_counter() - t_check:.1f} s; "
+                   f"host memory {host.start:.2f} GiB before it, at most "
+                   f"{host.peak:.2f} GiB during it")
         metrics, extra = {}, {}
         if traced:
             red = trace_mod.reduce(trace_mod.events_from_file(

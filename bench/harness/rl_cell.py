@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.harness import compare, device, fleet
+from bench.harness import compare, device, fleet, spec
 from bench.harness import trace as trace_mod
 from bench.references import ppo as ref_ppo
 
@@ -119,7 +119,8 @@ class RLRun:
         """Replay the logged acts and updates from the agents' initial
         weights; `dtype` is the precision of the whole replay. `keep="half"`
         updates on half of each buffer (a planted fault)."""
-        with device.reference_device(dtype):
+        with device.reference_device(dtype,
+                                     spec.reference_device(self.config)):
             return self._replay(dtype, keep)
 
     def _replay(self, dtype, keep) -> Dict:
@@ -149,9 +150,14 @@ class RLRun:
                         jnp.asarray(ev[1])[rows], name=name,
                         n_sizes=self.n_sizes, dtype=dtype)
                     params.append(jax.device_get(q))
-                    grads.append(jax.device_get(g0))
+                    grads.append(compare.norms(jax.device_get(g0)))
             out[name] = {"lps": lps, "params": params, "grads": grads}
         return out
+
+    def check(self) -> Dict:
+        """The numbers `correct` compares: the logged acts and updates
+        against their replay."""
+        return self.numbers(self.program_outputs(), self.reference())
 
     def numbers(self, outputs: Dict, ref: Dict) -> Dict:
         lp_gap, upd_gap = 0.0, 0.0

@@ -15,6 +15,17 @@ adding files:
 
 No module of the harness names a model family; a new client family is a
 new configuration and a new reference module.
+
+A configuration's optional `reference_device` says where its float32
+reference runs: `host` (the default, the host's CPU) or `chip` (the
+accelerator at "highest" matmul precision, for a family too large to
+replay on the host in a run's time).
+
+The training check replays the first round one client at a time (see
+`bench/harness/train_cell.py`): it holds the globals and a fixed number
+of client models in host memory, whatever the number of clients, so a
+family's functions take and give one client at a time, and its
+aggregation reads the trained clients from memory maps, leaf by leaf.
 """
 from __future__ import annotations
 
@@ -36,10 +47,15 @@ BENCH = ROOT / "bench"
 #   sim_config(config, traffic, fleet_seed)   the program's FLSimConfig
 #   init_params(key, config, size)      one model's initial weights (jittable)
 #   train_client(start, xs, ys, config, dtype, keep)   the reference's local
-#                                       training: (trained, first gradient)
+#                                       training of one client: (trained
+#                                       model as numpy, per-leaf float64
+#                                       norms of its first gradient)
 #   entropy(labels, n_classes), accuracy(params, x, y, dtype),
 #   aggregate(lite, by_size, sizes, trained, entropies, accs_local,
-#             accs_lite)                the reference's Eqs. 36-39 and 5
+#             accs_lite)                the reference's Eqs. 36-39 and 5;
+#                                       `trained[i]` is client i as memory
+#                                       maps: read a leaf at a time, never
+#                                       every client whole
 #   step_flops(config, size), step_bytes(config, size)   least operations
 #                                       and bytes of one client step
 FAMILY_API = ("check_config", "check_data", "sim_config", "init_params",
@@ -47,8 +63,22 @@ FAMILY_API = ("check_config", "check_data", "sim_config", "init_params",
               "step_flops", "step_bytes")
 
 
+REFERENCE_DEVICES = ("host", "chip")
+
+
 class SpecError(ValueError):
     """A cell, file or configuration that does not match the benchmark."""
+
+
+def reference_device(config: Dict) -> str:
+    """Where the configuration's float32 reference runs (`host` unless the
+    config says otherwise); SpecError for a value not in
+    REFERENCE_DEVICES."""
+    where = config.get("reference_device", "host")
+    if where not in REFERENCE_DEVICES:
+        raise SpecError(f"config {config.get('name')!r} states reference_"
+                        f"device {where!r}; known: {REFERENCE_DEVICES}")
+    return where
 
 
 @dataclass
@@ -123,6 +153,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if config.get("name") != entry["name"]:
         raise SpecError(f"{entry['file']} holds config {config.get('name')!r}"
                         f", not {entry['name']!r}")
+    reference_device(config)
     traffic = _read_json(root / "bench" / "traffic" / f"{w['traffic']}.json")
     limits = _read_json(root / "bench" / "limits" / f"{name}.json")
     e2e = [m for m in bench["end_to_end"]

@@ -154,7 +154,8 @@ def train_client(start, xs, ys, config: Dict, dtype=jnp.float32,
 
     `keep`, where given, is the number of rows of each batch to use (a
     control that leaves part of the batch out). Returns the trained params
-    (float32 numpy) and the first step's gradient (numpy)."""
+    (float32 numpy) and the float64 norm of each leaf of the first step's
+    gradient, in the order of the params' leaves."""
     p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, dtype), start)
     mu = jax.tree_util.tree_map(jnp.zeros_like, p)
     g0 = None
@@ -165,10 +166,11 @@ def train_client(start, xs, ys, config: Dict, dtype=jnp.float32,
                                lr=config["lr"], momentum=config["momentum"],
                                dtype=dtype)
         if g0 is None:
-            g0 = g
-    to_np = functools.partial(jax.tree_util.tree_map,
-                              lambda a: np.asarray(a, np.float32))
-    return to_np(p), to_np(g0)
+            g0 = np.array([np.linalg.norm(np.asarray(a, np.float32)
+                                          .astype(np.float64))
+                           for a in jax.tree_util.tree_leaves(g)])
+    return jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32),
+                                  p), g0
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
@@ -206,8 +208,10 @@ def weights(entropies: Sequence[float], accs: Sequence[float]) -> np.ndarray:
     return 0.5 * (_softmax(entropies) + _softmax(accs))
 
 
-def fold(theta, clients: List, w: np.ndarray):
-    """theta + sum_i w_i (theta_i - theta), in float64."""
+def fold(theta, clients: Sequence, w: np.ndarray):
+    """theta + sum_i w_i (theta_i - theta), in float64, one leaf at a time:
+    a client's leaf is read (from its memory map) only while it is
+    added."""
     w = np.asarray(w, np.float64) / np.sum(w)
 
     def leaf(t, *cs):
@@ -217,10 +221,11 @@ def fold(theta, clients: List, w: np.ndarray):
     return jax.tree_util.tree_map(leaf, theta, *clients)
 
 
-def aggregate(lite, by_size: Dict, sizes: Sequence[str], trained: List,
+def aggregate(lite, by_size: Dict, sizes: Sequence[str], trained: Sequence,
               entropies: Sequence[float], accs_local: Sequence[float],
               accs_lite: Sequence[float]):
-    """Eq. 38-39 over LiteModels, Eq. 5 per size group over local models."""
+    """Eq. 38-39 over LiteModels, Eq. 5 per size group over local models.
+    `trained[i]` is client i's {local, lite}, as memory maps or arrays."""
     new_lite = fold(lite, [t["lite"] for t in trained],
                     weights(entropies, accs_lite))
     new = dict(by_size)

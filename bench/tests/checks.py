@@ -11,8 +11,9 @@ import json
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from bench.harness import compare, fleet, rl_cell, spec, train_cell
+from bench.harness import compare, device, fleet, rl_cell, spec, train_cell
 
 TRAIN = {"n_clients": 6, "k_per_round": 3, "default_epochs": 2,
          "n_train": 360, "n_test": 60, "rl_warmup_rounds": 5,
@@ -55,13 +56,82 @@ def with_fault(monkeypatch, plant):
     monkeypatch.setattr(fleet, "build", planted)
 
 
-def training_verdict(cell, seed=5):
+def training_numbers(cell, seed=5):
+    """The numbers of a run's check, as `bench/run.py` takes them."""
     run = train_cell.TrainingRun(cell, seed)
     run.setup(warm=False)
-    prog = run.program_outputs()
     run.release()
-    return compare.verdict(run.numbers(prog, run.reference()),
+    return run.check()
+
+
+def training_verdict(cell, seed=5):
+    return compare.verdict(training_numbers(cell, seed),
                            cell.limits["limits"])
+
+
+def whole_round(first, dtype=jnp.float32, keep=None):
+    """The reference's first round with every client held at once: the
+    training check as it was before it went one client at a time, kept as
+    the reference of the per-client walk."""
+    fam, where = first.fam, spec.reference_device(first.config)
+    clients, sizes, _ = first.plan
+    trained, grads = [], []
+    for i, c in enumerate(clients):
+        xs, ys = first.batches[int(c)]
+        with device.reference_device(dtype, where):
+            p, g = fam.train_client(first.start(i), xs, ys, first.config,
+                                    dtype, keep)
+        trained.append(p)
+        grads.append(g)
+    data = first.data
+    x_tr, y_tr, parts = data["x_train"], data["y_train"], data["parts"]
+    ents = [fam.entropy(y_tr[parts[c]], first.config["n_classes"])
+            for c in clients]
+    accs = {}
+    if first.evals:
+        for c, p in zip(clients, trained):
+            for which in ("local", "lite"):
+                accs[("client", which, int(c))] = fam.accuracy(
+                    p[which], x_tr[parts[c]], y_tr[parts[c]], dtype)
+    acc_loc = [accs.get(("client", "local", int(c)), 0.0) for c in clients]
+    acc_lite = [accs.get(("client", "lite", int(c)), 0.0) for c in clients]
+    with device.reference_device(dtype, where):
+        lite, by_size = fam.aggregate(
+            first.w0["lite"],
+            {s: first.w0[s] for s in first.config["size_names"]}, sizes,
+            trained, ents, acc_loc, acc_lite)
+    after = {"lite": lite, **by_size}
+    if first.evals:
+        for name, p in after.items():
+            accs[("global", name)] = fam.accuracy(
+                p, data["x_test"], data["y_test"], dtype)
+    return {"trained": trained, "grads": grads, "after": after, "accs": accs}
+
+
+def whole_outputs(side, n_clients):
+    """A side of the walk as a whole round in memory."""
+    return {"trained": [side.client(i) for i in range(n_clients)],
+            "after": side.after(), "accs": side.accs}
+
+
+def whole_numbers(first, outputs, ref):
+    """One whole round's numbers against the reference's, as the check
+    took them before it went one client at a time."""
+    _, sizes, _ = first.plan
+    client = [compare.change_gaps(outputs["trained"][i], ref["trained"][i],
+                                  first.start(i), ref["grads"][i])
+              for i in range(len(sizes))]
+    glob = [compare.change_gaps(outputs["after"][n], ref["after"][n],
+                                first.w0[n])
+            for n in ["lite"] + sorted(set(sizes))]
+    out = {"client_change_gap": max(float(np.nanmax(g)) for g in client),
+           "client_median_gap": max(float(np.nanmedian(g)) for g in client),
+           "global_change_gap": max(float(np.nanmax(g)) for g in glob),
+           "global_median_gap": max(float(np.nanmedian(g)) for g in glob)}
+    if ref["accs"]:
+        out["accuracy_gap"] = max(abs(outputs["accs"][key] - v)
+                                  for key, v in ref["accs"].items())
+    return out
 
 
 def rl_verdict(cell, seed=5):
@@ -126,22 +196,26 @@ def rl_altered(srv):
     agent._act = act
 
 
-
-
 def sound_and_control(cell, seed=5):
     """The verdicts of a sound run and of the control on the same first
     steps."""
-    kind = rl_cell.RLRun if cell.traffic["latency_only"] \
-        else train_cell.TrainingRun
-    run = kind(cell, seed)
-    if kind is train_cell.TrainingRun:
+    lim = cell.limits["limits"]
+    if not cell.traffic["latency_only"]:
+        run = train_cell.TrainingRun(cell, seed)
         run.setup(warm=False)
-    else:
-        run.setup()
+        run.release()
+        try:
+            res = run.readings([run.program(),
+                                run.reference("control", jnp.bfloat16)])
+        finally:
+            run.close()
+        return (compare.verdict(res["program"]["numbers"], lim),
+                compare.verdict(res["control"]["numbers"], lim))
+    run = rl_cell.RLRun(cell, seed)
+    run.setup()
     prog = run.program_outputs()
     run.release()
     ref = run.reference()
-    lim = cell.limits["limits"]
     return (compare.verdict(run.numbers(prog, ref), lim),
             compare.verdict(run.numbers(run.reference(jnp.bfloat16), ref),
                             lim))

@@ -122,10 +122,8 @@ def test_a_training_run_takes_the_model_from_the_family(monkeypatch):
     monkeypatch.setattr(fam, "init_params", init_params)
     run = train_cell.TrainingRun(cell, 5)
     run.setup(warm=False)
-    prog = run.program_outputs()
     run.release()
-    verdict = compare.verdict(run.numbers(prog, run.reference()),
-                              cell.limits["limits"])
+    verdict = compare.verdict(run.check(), cell.limits["limits"])
     assert verdict["correct"]
     assert calls["train_client"] == cell.traffic["k_per_round"]
     assert sorted(calls["init_params"]) == sorted(
