@@ -14,7 +14,20 @@ wall-clock grows linearly with cohort size. This engine instead:
   3. runs ONE jitted `jax.vmap`-over-clients of a `jax.lax.scan`-over-steps
      mutual-KD train step per group. Padded steps are computed but their
      updates are discarded with `jnp.where` on the (clients, S) step mask,
-     so ragged intensities stay exact.
+     so ragged intensities stay exact;
+  4. pipelines the groups two deep: group g+1 is prefetched, padded, put
+     on the device and dispatched while group g still trains, and only
+     then does the host wait for g, bring its stack back in one transfer
+     (started with `copy_to_host_async` as g was dispatched) and split it
+     into per-client numpy views. The device finds g+1 queued, inputs
+     included, when g ends; the host's preparation of g+1 and the copy
+     back of g hide behind the scans. Two is enough: the device runs one
+     group at a time, and one group queued behind the running one keeps
+     it fed wherever the host prepares a group faster than the device
+     trains one. A deeper queue would help only behind the shortest scans,
+     and would hold one more group's inputs (up to hundreds of MB of image
+     batches) on the device. The host's calls keep their order, so the
+     loaders' rng streams and every result are unchanged.
 
 Because `sample_many` reproduces `sample()`'s rng stream element-for-element
 and masked steps never touch parameters, the engine matches the sequential
@@ -171,6 +184,8 @@ class BatchedClientEngine:
         for i, (c, s) in enumerate(zip(clients, sizes)):
             sb = next_pow2(int(intensities[i]) * bpe) if pad_pow2 else 0
             groups.setdefault((s, env.loaders[c].batch_size, sb), []).append(i)
+        tr = _tracer()
+        pending = None          # the dispatched, uncollected group
         for (s, _, _), idx in groups.items():
             steps = [int(intensities[i]) * bpe for i in idx]
             S = next_pow2(max(steps)) if pad_pow2 else max(steps)
@@ -189,11 +204,26 @@ class BatchedClientEngine:
             start = {"local": global_by_size[s], "lite": lite_params}
             # names the group's vmap+scan dispatch both in our tracer (wall
             # span) and in any active jax.profiler trace
-            with _tracer().annotation(self._group_label(s, Cp, S)):
+            with tr.annotation(self._group_label(s, Cp, S)):
                 trained = self._dispatch(s, start, xs, ys, mask)
-                # one device->host transfer per group; per-client numpy
-                # views avoid spawning ~10 device slice ops per client
-                host = jax.device_get(trained)
-            for j, i in enumerate(idx):
-                out[i] = jax.tree_util.tree_map(lambda a: a[j], host)
+            # the copy back starts as soon as the group's program ends
+            for leaf in jax.tree_util.tree_leaves(trained):
+                leaf.copy_to_host_async()
+            tr.count("cohort.groups")
+            if pending is not None:
+                tr.count("cohort.overlapped")
+                _collect(*pending, out)
+            pending = (idx, trained)
+        if pending is not None:
+            _collect(*pending, out)
         return out
+
+
+def _collect(idx, trained, out) -> None:
+    """Wait for a dispatched group's trained stack, bring it to the host in
+    one transfer and split it into per-client numpy views (no device slice
+    ops) at `out[i]` for each input position i in `idx`."""
+    with _tracer().span("cohort.collect"):
+        host = jax.device_get(trained)
+    for j, i in enumerate(idx):
+        out[i] = jax.tree_util.tree_map(lambda a: a[j], host)

@@ -97,3 +97,92 @@ def test_full_round_server_parity():
 def test_unknown_engine_rejected():
     with pytest.raises(ValueError):
         HAPFLServer(FLEnvironment(CFG), engine="warp-drive")
+
+
+@pytest.fixture(scope="module")
+def trainers():
+    """One engine's compiled group trainers, shared by the pipeline tests'
+    engines so that each cohort shape compiles once."""
+    return BatchedClientEngine(FLEnvironment(CFG))._trainers
+
+
+def _engine(trainers):
+    env = FLEnvironment(CFG)
+    eng = BatchedClientEngine(env)
+    eng._trainers = trainers
+    return env, eng
+
+
+def _groups(env, clients, sizes, intensities):
+    """The cohort's size groups in dispatch order, as input positions."""
+    bpe = env.cfg.batches_per_epoch
+    groups = {}
+    for i, (c, s) in enumerate(zip(clients, sizes)):
+        key = (s, env.loaders[c].batch_size,
+               1 << max(intensities[i] * bpe - 1, 0).bit_length())
+        groups.setdefault(key, []).append(i)
+    return list(groups.values())
+
+
+def test_pipelined_cohort_equals_one_call_per_group(trainers):
+    """Overlapping a group's dispatch with the previous group's collection
+    changes when the host waits, never what is computed: the cohort in one
+    call gives the same arrays as one call per group on a twin, and leaves
+    every loader's rng where the twin's is."""
+    (env_a, eng_a), (env_b, eng_b) = _engine(trainers), _engine(trainers)
+    srv = HAPFLServer(env_a, seed=0)   # only for shared initial globals
+    clients = [0, 1, 2, 3, 4]
+    sizes = ["small", "small", "large", "large", "small"]
+    intensities = [1, 2, 1, 1, 2]
+    groups = _groups(env_a, clients, sizes, intensities)
+    assert len(groups) >= 3
+    whole = eng_a.train_cohort(clients, sizes, intensities,
+                               srv.global_by_size, srv.lite_params)
+    for idx in groups:
+        part = eng_b.train_cohort(
+            [clients[i] for i in idx], [sizes[i] for i in idx],
+            [intensities[i] for i in idx], srv.global_by_size,
+            srv.lite_params)
+        for i, p in zip(idx, part):
+            for la, lb in zip(_leaves(whole[i]), _leaves(p)):
+                assert isinstance(la, np.ndarray)
+                assert np.array_equal(la, lb)
+    for la, lb in zip(env_a.loaders, env_b.loaders):
+        assert la.rng.bit_generator.state == lb.rng.bit_generator.state
+
+
+@pytest.mark.parametrize("n_groups", [1, 2, 4])
+def test_two_groups_in_flight_at_most(trainers, n_groups):
+    """Each group is counted; every group but the first is dispatched while
+    its predecessor is still uncollected, and never a third."""
+    from repro.obs import trace as obs_trace
+    env, eng = _engine(trainers)
+    srv = HAPFLServer(env, seed=0)
+    # (size, intensity) per client; clients 0, 1, 2, 4 share a batch size
+    plan = [("small", 1), ("small", 2), ("large", 1), ("large", 2)]
+    clients = [0, 1, 2, 4][:n_groups]
+    sizes, intensities = (list(v) for v in zip(*plan[:n_groups]))
+    assert len(_groups(env, clients, sizes, intensities)) == n_groups
+    dispatch, uncollected = eng._dispatch, []
+
+    def spy(*args):
+        collected = sum(ev["name"] == "cohort.collect" for ev in tr.events)
+        uncollected.append(len(uncollected) + 1 - collected)
+        return dispatch(*args)
+
+    eng._dispatch = spy
+    prev = obs_trace.current()
+    tr = obs_trace.enable(obs_trace.Tracer())
+    try:
+        out = eng.train_cohort(clients, sizes, intensities,
+                               srv.global_by_size, srv.lite_params)
+    finally:
+        obs_trace.enable(prev)           # whichever tracer was installed
+    assert len(out) == n_groups and all(p is not None for p in out)
+    assert tr.counts["cohort.groups"] == n_groups
+    assert tr.counts.get("cohort.overlapped", 0) == n_groups - 1
+    assert max(uncollected) == min(n_groups, 2)
+    collects = [ev for ev in tr.events if ev["name"] == "cohort.collect"]
+    assert len(collects) == n_groups
+    # the group's annotation covers its dispatch, not the wait for it
+    assert all(ev["parent"] is None for ev in collects)
