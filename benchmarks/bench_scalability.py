@@ -29,9 +29,9 @@ def reduction(cfg, warmup, eval_rounds, seed=0):
     f = []
     for r in range(eval_rounds):
         clients = env2.select_clients()
-        times = [env2.latency.local_train_time(
-            env2.profiles[c], r, size, cfg.default_epochs, include_lite=False)
-            for c in clients]
+        times = env2.latency.local_train_times(
+            env2.store, clients, r, [size] * len(clients),
+            [cfg.default_epochs] * len(clients), include_lite=False)
         f.append(max(times) - min(times))
     return float(100 * (1 - h / np.mean(f)))
 

@@ -26,7 +26,7 @@ def main():
                       default_epochs=8, batches_per_epoch=2, lr=1e-2)
     env = FLEnvironment(cfg)
     print(f"clients: {cfg.n_clients}, per-round: {cfg.k_per_round}, "
-          f"speeds: {[round(p.base_speed, 1) for p in env.profiles]}")
+          f"speeds: {[round(float(v), 1) for v in env.store.base_speed]}")
     srv = HAPFLServer(env, seed=0)   # engine="auto" picks per regime
     print(f"training engine: {srv.engine}")
 

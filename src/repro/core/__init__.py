@@ -18,6 +18,5 @@ from repro.core.aggregation import (information_entropy, aggregation_weights,
                                     group_aggregate)
 from repro.core.nested import (extract_submodel, embed_submodel,
                                coverage_mask, nested_aggregate)
-from repro.core.latency import (ClientProfile, LatencyModel,
-                                make_heterogeneous_clients, straggling_latency)
+from repro.core.latency import LatencyModel, straggling_latency
 from repro.core.population import ClientStore

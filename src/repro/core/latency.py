@@ -11,6 +11,10 @@ client latencies in arrival order, not cohort order, so a shared-stream
 draw would make the simulated environment depend on the scheduling policy;
 counter-based draws make sync and event-driven runs byte-identical.
 
+The per-client fields (base speed, dataset size, drift and jitter
+parameters) live in a `repro.core.population.ClientStore`; every latency
+query reads a whole cohort out of it in one numpy pass.
+
 Also here: the communication model (upload/download time = payload bytes /
 per-client bandwidth) and on/off availability traces used by the
 event-driven simulator (DESIGN.md §10).
@@ -25,13 +29,6 @@ import numpy as np
 
 _M64 = (1 << 64) - 1
 _U64 = np.uint64
-
-
-def _splitmix64(x: int) -> int:
-    x = (x + 0x9E3779B97F4A7C15) & _M64
-    x = ((x ^ (x >> 30)) * 0xBF58476D1CE4E5B9) & _M64
-    x = ((x ^ (x >> 27)) * 0x94D049BB133111EB) & _M64
-    return x ^ (x >> 31)
 
 
 def _splitmix64_np(x: np.ndarray) -> np.ndarray:
@@ -53,8 +50,8 @@ def counter_normal_array(*entropy) -> np.ndarray:
     an int or an integer ndarray; items broadcast together, and element i
     of the result equals the scalar draw keyed by element i of every item.
     Scalar-only inputs yield a shape-(1,) array. One splitmix64 avalanche
-    per entropy item + Box-Muller, all in uint64/float64 numpy — the SoA
-    population path draws a whole cohort's jitter in one call."""
+    per entropy item + Box-Muller, all in uint64/float64 numpy — a whole
+    cohort's jitter in one call."""
     shape = np.broadcast_shapes(*(np.shape(e) for e in entropy))
     flat = shape if shape else (1,)
     x = np.zeros(flat, _U64)
@@ -65,21 +62,11 @@ def counter_normal_array(*entropy) -> np.ndarray:
     return np.sqrt(-2.0 * np.log(u1)) * np.cos(2.0 * np.pi * u2)
 
 
-def _counter_normal(*entropy: int) -> float:
-    """Standard-normal draw keyed purely by the given integers (splitmix64
-    avalanche + Box-Muller) — the same value no matter when or in what
-    order it is queried. Delegates to the vectorized kernel so the scalar
-    (legacy dict-of-objects) and array (SoA population) paths are bitwise
-    identical by construction."""
-    return float(counter_normal_array(*entropy)[0])
-
-
 def profile_speeds(base_speed, client_id, drift_amp, drift_period,
                    jitter_sigma, round_idx: int, seed: int = 0) -> np.ndarray:
-    """Vectorized ClientProfile.speed_at over parallel per-client arrays
-    (sinusoidal drift + counter-keyed lognormal jitter). Scalars broadcast;
-    ClientProfile.speed_at routes through here with size-1 inputs, so both
-    paths share every floating-point op."""
+    """Per-client speeds at one round over parallel per-client arrays:
+    base speed times a slow sinusoidal drift times a counter-keyed
+    lognormal(0, sigma) jitter. Scalars broadcast."""
     base_speed = np.asarray(base_speed, np.float64)
     client_id = np.asarray(client_id, np.int64)
     drift_amp = np.asarray(drift_amp, np.float64)
@@ -100,34 +87,6 @@ def _counter_rng(*entropy: int) -> np.random.Generator:
         np.random.SeedSequence([int(e) & 0xFFFFFFFF for e in entropy]))
 
 
-@dataclass
-class ClientProfile:
-    client_id: int
-    base_speed: float          # effective samples*cost-units per second
-    dataset_size: int
-    drift_amp: float = 0.2     # slow sinusoidal capability drift
-    drift_period: float = 50.0
-    jitter_sigma: float = 0.05 # per-round lognormal noise
-
-    def speed_at(self, round_idx: int, seed: int = 0) -> float:
-        # lognormal(0, sigma) jitter = exp(sigma * N(0, 1)), counter-keyed;
-        # shares the vectorized kernel with the SoA population path
-        return float(profile_speeds(
-            self.base_speed, self.client_id, self.drift_amp,
-            self.drift_period, self.jitter_sigma, round_idx, seed)[0])
-
-
-def make_heterogeneous_clients(n_clients: int, max_speed_ratio: float,
-                               dataset_sizes: Sequence[int], seed: int = 0,
-                               ) -> List[ClientProfile]:
-    """Speeds log-spaced across `max_speed_ratio` (paper: 10x/20x/50x)."""
-    rng = np.random.default_rng(seed)
-    speeds = np.geomspace(1.0, max_speed_ratio, n_clients)
-    rng.shuffle(speeds)
-    return [ClientProfile(i, float(s), int(d))
-            for i, (s, d) in enumerate(zip(speeds, dataset_sizes))]
-
-
 class LatencyModel:
     """Computes T^d (assessment), T^l (local training) per Eqs. 7-10.
 
@@ -144,33 +103,13 @@ class LatencyModel:
         self.cost_scale = cost_scale
         self.seed = seed
 
-    def assessment_time(self, profile: ClientProfile, round_idx: int) -> float:
-        """T^d: one LiteModel epoch (paper §IV.B)."""
-        speed = profile.speed_at(round_idx, self.seed)
-        return profile.dataset_size * self.lite_cost * self.cost_scale / speed
-
-    def local_train_time(self, profile: ClientProfile, round_idx: int,
-                         size_name: str, intensity: int,
-                         include_lite: bool = True) -> float:
-        """T^l: `intensity` local iterations of (local model [+ LiteModel])
-        mutual-learning training (Eq. 9-10). Baselines without a LiteModel
-        pass include_lite=False."""
-        speed = profile.speed_at(round_idx, self.seed)
-        cost = self.model_costs[size_name] + (self.lite_cost if include_lite
-                                              else 0.0)
-        per_epoch = profile.dataset_size * cost * self.cost_scale / speed
-        return max(int(intensity), 1) * per_epoch
-
     def relative_time_ratio(self, size_name: str) -> float:
         """M(.) in Eq. 24: cost of category relative to the LiteModel."""
         return (self.model_costs[size_name] + self.lite_cost) / self.lite_cost
 
-    # ---- vectorized (struct-of-arrays) queries -------------------------- #
-    # element i of each result is bitwise equal to the corresponding scalar
-    # query: the scalar path delegates to the same kernels, so the SoA
-    # population path and the legacy per-profile loop cannot diverge.
     def assessment_times(self, store, clients, round_idx: int) -> np.ndarray:
-        """T^d for a whole cohort out of a ClientStore, one numpy pass."""
+        """T^d: one LiteModel epoch (paper §IV.B) for a whole cohort out of
+        a ClientStore, one numpy pass."""
         c = np.asarray(clients, np.int64)
         speed = store.speeds_at(c, round_idx, self.seed)
         return store.dataset_size[c] * self.lite_cost * self.cost_scale / speed
@@ -178,7 +117,10 @@ class LatencyModel:
     def local_train_times(self, store, clients, round_idx: int,
                           size_names: Sequence[str], intensities,
                           include_lite: bool = True) -> np.ndarray:
-        """T^l for a whole cohort out of a ClientStore, one numpy pass."""
+        """T^l: `intensity` local iterations of (local model [+ LiteModel])
+        mutual-learning training (Eq. 9-10) for a whole cohort out of a
+        ClientStore, one numpy pass. Baselines without a LiteModel pass
+        include_lite=False."""
         c = np.asarray(clients, np.int64)
         speed = store.speeds_at(c, round_idx, self.seed)
         lite = self.lite_cost if include_lite else 0.0

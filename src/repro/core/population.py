@@ -1,13 +1,9 @@
-"""Struct-of-arrays client state for population-scale FL (DESIGN.md §15).
+"""Struct-of-arrays client state (DESIGN.md §15).
 
-The legacy server keeps per-client state as dicts of Python objects
-(ClientProfile instances, per-ticket Ticket objects, EF residual dicts,
-per-client availability trace lists). That layout is fine at 10-100
-clients and fatal at 100k+: object headers dominate memory, and every
-cohort operation is a Python-level loop.
-
-`ClientStore` flips the layout: one contiguous numpy array per field,
-indexed by client id. It holds
+`ClientStore` is the one home of per-client server state: one contiguous
+numpy array per field, indexed by client id, so memory stays a few
+hundred bytes a client at 100k+ clients and every cohort operation is a
+numpy pass rather than a Python loop. It holds
 
   * the latency-profile fields (base_speed, dataset_size, drift params)
     that `repro.core.latency.profile_speeds` consumes vectorized,
@@ -25,10 +21,8 @@ per client at all — tickets pin dispatch-time globals by reference, so
 only the active cohort materializes trees (the memory-shape tests pin
 this).
 
-The store is *observational* with respect to learning: nothing in the
-aggregation, PPO, or codec math reads the history arrays, so the SoA and
-legacy paths produce byte-identical rounds (pinned in
-tests/test_population.py).
+The history arrays are *observational*: nothing in the aggregation, PPO,
+or codec math reads them back.
 """
 from __future__ import annotations
 
@@ -85,39 +79,45 @@ class ClientStore:
     # ------------------------------------------------------------------ #
     # constructors
     # ------------------------------------------------------------------ #
+    @staticmethod
+    def _speeds(n_clients: int, max_speed_ratio: float,
+                seed: int) -> np.ndarray:
+        """Base speeds log-spaced across `max_speed_ratio` (paper:
+        10x/20x/50x), shuffled by a generator seeded with `seed`."""
+        rng = np.random.default_rng(seed)
+        speeds = np.geomspace(1.0, max_speed_ratio, n_clients)
+        rng.shuffle(speeds)
+        return speeds
+
     @classmethod
-    def from_profiles(cls, profiles, entropies,
+    def for_partition(cls, n_clients: int, max_speed_ratio: float,
+                      dataset_sizes: Sequence[int], entropies,
+                      seed: int = 0,
                       size_names: Sequence[str] = ()) -> "ClientStore":
-        """Mirror a list of ClientProfile objects (the legacy layout) into
-        arrays — the small-cohort FLEnvironment path."""
-        return cls(
-            base_speed=np.array([p.base_speed for p in profiles]),
-            dataset_size=np.array([p.dataset_size for p in profiles]),
-            entropy=np.asarray(entropies, np.float64),
-            size_names=size_names,
-            drift_amp=np.array([p.drift_amp for p in profiles]),
-            drift_period=np.array([p.drift_period for p in profiles]),
-            jitter_sigma=np.array([p.jitter_sigma for p in profiles]))
+        """The fleet of a real data partition: `dataset_sizes` and
+        `entropies` come from each client's shard (FLEnvironment)."""
+        return cls(base_speed=cls._speeds(n_clients, max_speed_ratio, seed),
+                   dataset_size=np.asarray(dataset_sizes, np.int64),
+                   entropy=np.asarray(entropies, np.float64),
+                   size_names=size_names)
 
     @classmethod
     def synthetic(cls, n_clients: int, max_speed_ratio: float,
                   mean_dataset_size: int = 300, seed: int = 0,
                   size_names: Sequence[str] = ()) -> "ClientStore":
-        """Population-scale constructor: no per-client objects are ever
-        built. Speeds are log-spaced and shuffled exactly like
-        `make_heterogeneous_clients`; dataset sizes are lognormal around
-        the mean (the non-IID partition analogue) and entropies uniform in
-        [0.5, log2(10)], both from a separate counter-keyed stream so the
-        speed layout matches the object path for equal (n, ratio, seed)."""
-        rng = np.random.default_rng(seed)
-        speeds = np.geomspace(1.0, max_speed_ratio, n_clients)
-        rng.shuffle(speeds)
+        """Population-scale constructor with no dataset behind it. Speeds
+        are drawn exactly like `for_partition`'s; dataset sizes are
+        lognormal around the mean (the non-IID partition analogue) and
+        entropies uniform in [0.5, log2(10)], both from a separate
+        counter-keyed stream so the speed layout matches `for_partition`
+        for equal (n, ratio, seed)."""
         aux = np.random.default_rng(
             np.random.SeedSequence([seed & 0xFFFFFFFF, 0x90901A7]))
         sizes = np.maximum(
             (mean_dataset_size * aux.lognormal(0.0, 0.5, n_clients)), 16.0)
         entropy = aux.uniform(0.5, np.log2(10.0), n_clients)
-        return cls(base_speed=speeds, dataset_size=sizes.astype(np.int64),
+        return cls(base_speed=cls._speeds(n_clients, max_speed_ratio, seed),
+                   dataset_size=sizes.astype(np.int64),
                    entropy=entropy, size_names=size_names)
 
     # ------------------------------------------------------------------ #
@@ -164,7 +164,7 @@ class ClientStore:
 
     def expired_clients(self, now: float) -> np.ndarray:
         """In-flight clients whose deadline passed, ordered by
-        (deadline, client) — exactly the legacy poll() expiry order."""
+        (deadline, client)."""
         hit = np.flatnonzero(self.inflight & (self.ticket_deadline < now))
         if hit.size == 0:
             return hit

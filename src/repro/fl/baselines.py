@@ -89,38 +89,35 @@ class BaselineRunner:
         env, cfg = self.env, self.env.cfg
         r = self._round
         clients = env.select_clients()
-        assess = [env.latency.assessment_time(env.profiles[c], r)
-                  for c in clients]
+        assess = [float(a) for a in env.latency.assessment_times(
+            env.store, clients, r)]
+        size_names = [self.size] * len(clients)
         if self.algo == "fedddrl":
             self.key, k = jax.random.split(self.key)
             intensities, _ = self.intensity.assign(
                 k, (np.asarray(assess) / min(assess)).tolist())
             # early client termination: cap the slowest client's epochs
-            t_pred = [env.latency.local_train_time(env.profiles[c], r,
-                                                   self.size, e,
-                                                   include_lite=False)
-                      for c, e in zip(clients, intensities)]
+            t_pred = env.latency.local_train_times(
+                env.store, clients, r, size_names, intensities,
+                include_lite=False)
             worst = int(np.argmax(t_pred))
             intensities[worst] = max(1, intensities[worst] // 2)
         else:
             intensities = [cfg.default_epochs] * len(clients)
 
-        local_times, client_params, client_acc = [], [], {}
-        for c, e in zip(clients, intensities):
-            t_l = env.latency.local_train_time(env.profiles[c], r, self.size,
-                                               e, include_lite=False)
-            local_times.append(t_l)
-            if latency_only:
-                continue
-            start = (self.personal[c] if self.algo == "pfedme"
-                     else self.global_params)
-            p = self._train_client(c, e, start)
-            client_params.append(p)
-            if self.algo == "pfedme":
-                self.personal[c] = p
-            client_acc[c] = env.client_test_accuracy(p, self.cnn_cfg, c)
-
+        local_times = [float(t) for t in env.latency.local_train_times(
+            env.store, clients, r, size_names, intensities,
+            include_lite=False)]
+        client_params, client_acc = [], {}
         if not latency_only:
+            for c, e in zip(clients, intensities):
+                start = (self.personal[c] if self.algo == "pfedme"
+                         else self.global_params)
+                p = self._train_client(c, e, start)
+                client_params.append(p)
+                if self.algo == "pfedme":
+                    self.personal[c] = p
+                client_acc[c] = env.client_test_accuracy(p, self.cnn_cfg, c)
             sizes = [len(env.partitions[c]) for c in clients]
             self.global_params = fedavg_aggregate(client_params, sizes)
         if self.algo == "fedddrl":

@@ -12,7 +12,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.core.latency import LatencyModel, make_heterogeneous_clients
+from repro.core.latency import LatencyModel
 from repro.core.aggregation import information_entropy
 from repro.core.population import ClientStore
 from repro.data import (BatchLoader, dirichlet_partition, label_histogram,
@@ -79,13 +79,10 @@ class FLEnvironment:
         self.latency = LatencyModel(
             {s: float(c.num_params()) for s, c in self.pool.items()},
             float(self.lite_cfg.num_params()), seed=cfg.seed)
-        self.profiles = make_heterogeneous_clients(
-            cfg.n_clients, cfg.max_speed_ratio,
-            [len(p) for p in parts], seed=cfg.seed)
-        # struct-of-arrays mirror of the per-client state (DESIGN.md §15);
-        # the server routes latency queries through it vectorized
-        self.store = ClientStore.from_profiles(
-            self.profiles, self.entropies, size_names=cfg.size_names)
+        # per-client state, struct-of-arrays (DESIGN.md §15)
+        self.store = ClientStore.for_partition(
+            cfg.n_clients, cfg.max_speed_ratio, [len(p) for p in parts],
+            self.entropies, seed=cfg.seed, size_names=cfg.size_names)
         self.rng = np.random.default_rng(cfg.seed + 99)
 
     # ------------------------------------------------------------------ #
@@ -145,15 +142,13 @@ class FLEnvironment:
 class PopulationEnv:
     """Latency/availability-only environment for population-scale
     simulation (DESIGN.md §15). Per-client state lives entirely in a
-    struct-of-arrays ClientStore — no datasets, loaders, or ClientProfile
-    objects are ever built, so a 100k-client environment costs megabytes
-    and constructs in milliseconds. Drives `HAPFLServer` through the same
-    wave callbacks as `FLEnvironment`, but only in latency_only mode
-    (plan -> PPO decisions -> feedback; no CNN training or accuracy
-    evaluation): pair with ``EventScheduler(latency_only=True,
-    eval_accuracy=False)`` or a ``ParamService``. Requires the server's
-    ClientStore path (``client_store=True``, the default) — there are no
-    profile objects for the legacy loop to read."""
+    struct-of-arrays ClientStore — no datasets or loaders are ever built,
+    so a 100k-client environment costs megabytes and constructs in
+    milliseconds. Drives `HAPFLServer` through the same wave callbacks as
+    `FLEnvironment`, but only in latency_only mode (plan -> PPO decisions
+    -> feedback; no CNN training or accuracy evaluation): pair with
+    ``EventScheduler(latency_only=True, eval_accuracy=False)`` or a
+    ``ParamService``."""
 
     def __init__(self, cfg: FLSimConfig, mean_dataset_size: int = 300):
         self.cfg = cfg

@@ -23,8 +23,8 @@ from repro.core.aggregation import (aggregation_weights, group_aggregate,
                                     information_entropy, weighted_aggregate)
 from repro.core.allocation import ModelAllocator
 from repro.core.intensity import IntensityAllocator
-from repro.core.latency import (LatencyModel, make_heterogeneous_clients,
-                                straggling_latency)
+from repro.core.latency import LatencyModel, straggling_latency
+from repro.core.population import ClientStore
 from repro.models.api import init_model
 from repro.models.transformer import apply_model
 from repro.train.step import (TrainStepConfig, make_hapfl_train_step,
@@ -82,9 +82,10 @@ class LLMFleet:
             self.client_tokens.append(toks)
             hist = np.bincount(toks, minlength=V)
             self.entropies.append(information_entropy(hist))
-        self.profiles = make_heterogeneous_clients(
+        self.store = ClientStore.for_partition(
             cfg.n_clients, cfg.max_speed_ratio,
-            [len(t) for t in self.client_tokens], seed=cfg.seed)
+            [len(t) for t in self.client_tokens], self.entropies,
+            seed=cfg.seed, size_names=tuple(self.pool))
         self.latency = LatencyModel(
             {s: float(c.num_params()) for s, c in self.pool.items()},
             float(self.lite.num_params()), cost_scale=1e-9, seed=cfg.seed)
@@ -119,18 +120,18 @@ class LLMFleet:
         r = self._round
         clients = sorted(self.rng.choice(cfg.n_clients, cfg.k_per_round,
                                          replace=False).tolist())
-        assess = [self.latency.assessment_time(self.profiles[c], r)
-                  for c in clients]
+        assess = [float(a) for a in self.latency.assessment_times(
+            self.store, clients, r)]
         self.key, k1, k2 = jax.random.split(self.key, 3)
         sizes, _ = self.allocator.allocate(k1, assess)
         modified = [self.latency.relative_time_ratio(s) * t / min(assess)
                     for s, t in zip(sizes, assess)]
         taus, _ = self.intensity.assign(k2, modified)
 
-        local_times, params_out, accs_local, accs_lite = [], [], [], []
+        local_times = [float(t) for t in self.latency.local_train_times(
+            self.store, clients, r, sizes, taus)]
+        params_out, accs_local, accs_lite = [], [], []
         for c, s, tau in zip(clients, sizes, taus):
-            local_times.append(self.latency.local_train_time(
-                self.profiles[c], r, s, tau))
             state = {"params": {"local": self.global_by_size[s],
                                 "lite": self.lite_params},
                      "opt": self.state_template[s]["opt"]}

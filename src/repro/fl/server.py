@@ -84,7 +84,7 @@ class HAPFLServer:
                  weighted_agg: bool = True,
                  lr_ppo1: float = 2e-3, lr_ppo2: float = 3e-4,
                  engine: str = "auto", aggregation: str = "group",
-                 codec=None, client_store: bool = True, mesh=None):
+                 codec=None, mesh=None):
         # paper Table II: lr1=0.02 — unstable for Adam on our tiny actor
         # (PPO1 reward degrades); 2e-3 learns cleanly (DESIGN.md §8).
         if engine not in ("auto", "batched", "sequential", "sharded"):
@@ -112,16 +112,14 @@ class HAPFLServer:
         self.codec_seed = seed
         # struct-of-arrays per-client state (DESIGN.md §15): latency
         # queries route through it vectorized, and the scheduler/service
-        # mirror their ticket slots into it. client_store=False keeps the
-        # legacy dict-of-objects loop alive for the bit-parity pin in
-        # tests/test_population.py; both paths are byte-identical.
-        self.store = getattr(env, "store", None) if client_store else None
+        # mirror their ticket slots into it
+        self.store = env.store
         # error-feedback residuals, keyed (client, kind, size) — "local"
         # trees change shape when PPO1 reassigns sizes, so each (client,
         # size) pair carries its own residual; "lite" is homogeneous.
-        # With a store this is the store's sparse EF dict (one home for
-        # per-client codec state), aliased so either handle works.
-        self._ef: Dict = {} if self.store is None else self.store.ef
+        # This is the store's sparse EF dict (one home for per-client
+        # codec state), aliased so either handle works.
+        self._ef: Dict = self.store.ef
         if engine == "auto":
             # batching wins when per-step compute is small (dispatch-bound
             # small batches) or the backend has parallel hardware; at large
@@ -233,16 +231,10 @@ class HAPFLServer:
         clients = [int(c) for c in clients]
         m = len(clients)
         # 1. performance assessment training (one Lite epoch, simulated
-        # time) — one vectorized pass over the ClientStore, or the legacy
-        # per-profile loop; element-for-element bitwise identical (the
-        # scalar latency path delegates to the same numpy kernels)
+        # time) — one vectorized pass over the ClientStore
         with tr.span("latency.assess"):
-            if self.store is not None:
-                assess = [float(a) for a in env.latency.assessment_times(
-                    self.store, clients, r)]
-            else:
-                assess = [env.latency.assessment_time(env.profiles[c], r)
-                          for c in clients]
+            assess = [float(a) for a in env.latency.assessment_times(
+                self.store, clients, r)]
         # 2. PPO1: model allocation
         with tr.span("server.split_key"):
             # two programs: the split and the unstacking of its result
@@ -266,17 +258,10 @@ class HAPFLServer:
         else:
             intensities = [cfg.default_epochs] * m
         with tr.span("latency.local_times"):
-            if self.store is not None:
-                local_times = [float(t) for t in
-                               env.latency.local_train_times(
-                                   self.store, clients, r, sizes,
-                                   intensities)]
-                self.store.note_plan(clients, assess, local_times, sizes,
-                                     intensities)
-            else:
-                local_times = [env.latency.local_train_time(
-                    env.profiles[c], r, s, tau)
-                    for c, s, tau in zip(clients, sizes, intensities)]
+            local_times = [float(t) for t in env.latency.local_train_times(
+                self.store, clients, r, sizes, intensities)]
+            self.store.note_plan(clients, assess, local_times, sizes,
+                                 intensities)
         return WavePlan(round_idx=r, clients=clients, assess=assess,
                         sizes=sizes, intensities=list(intensities),
                         local_times=local_times, latency_only=latency_only)

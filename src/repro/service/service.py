@@ -127,13 +127,12 @@ class ParamService:
         self.records: List[Dict] = []          # one entry per aggregation
         self._waves: Dict[int, Dict] = {}      # open waves (RL feedback)
         self._wave_count = 0
-        self._expired_once = set()             # clients seen churning (rejoin)
         # struct-of-arrays client state (DESIGN.md §15): ticket slots and
-        # churn flags mirror into it so deadline expiry and churn checks
-        # are array scans, not dict walks; the tickets dict stays the
-        # source of truth for reference pytrees (bounded by max_inflight —
-        # only the active cohort materializes trees)
-        self.store = getattr(server, "store", None)
+        # churn flags live in it so deadline expiry and churn checks are
+        # array scans, not dict walks; the tickets dict stays the source
+        # of truth for reference pytrees (bounded by max_inflight — only
+        # the active cohort materializes trees)
+        self.store = server.store
         # fleet health + SLOs (repro.obs.health / repro.obs.slo): both
         # observational — a service without them is byte-identical to one
         # never offered them. health=True builds a default tracker; slos
@@ -230,10 +229,9 @@ class ParamService:
                                  size=tk.size, intensity=tk.intensity,
                                  version=self.version,
                                  deadline=round(tk.deadline, 6))
-            if self.store is not None:
-                self.store.open_slots(admitted, w, list(range(m)),
-                                      self.version,
-                                      [tk.deadline for tk in tickets])
+            self.store.open_slots(admitted, w, list(range(m)),
+                                  self.version,
+                                  [tk.deadline for tk in tickets])
             if self.health is not None:
                 self.health.note_outcome("dispatched", m)
         self.metrics.dispatch_s.append(time.perf_counter() - t0)
@@ -268,8 +266,7 @@ class ParamService:
                              reason="no_ticket")
             self.metrics.submit_s.append(time.perf_counter() - t0)
             return SubmitReceipt(False, "no_ticket", version=self.version)
-        if self.store is not None:
-            self.store.close_slot(client, "update")
+        self.store.close_slot(client, "update")
         if self.health is not None:
             self.health.note_outcome("update")
         decoded, wire = self._ingest_decode(tk, params)
@@ -358,25 +355,18 @@ class ParamService:
         """Expire tickets whose deadline has passed — how clients that
         disappeared mid-round are detected. Their slots free up for the
         next dispatch; a later submit against an expired ticket is
-        rejected (`no_ticket`). With a ClientStore the scan is a
-        vectorized array pass in the same (deadline, client) order as the
-        legacy dict walk."""
+        rejected (`no_ticket`). The scan is one vectorized pass over the
+        ClientStore, in (deadline, client) order."""
         with _tracer().span("service.poll"):
             return self._poll(now)
 
     def _poll(self, now: float) -> int:
-        if self.store is not None:
-            expired = [self.tickets[int(c)]
-                       for c in self.store.expired_clients(now)]
-        else:
-            expired = sorted((tk for tk in self.tickets.values()
-                              if tk.deadline < now),
-                             key=lambda tk: (tk.deadline, tk.client))
+        expired = [self.tickets[int(c)]
+                   for c in self.store.expired_clients(now)]
         for tk in expired:
             del self.tickets[tk.client]
-            if self.store is not None:
-                self.store.close_slot(tk.client, "expired")
-            self._note_expired(tk.client)
+            self.store.close_slot(tk.client, "expired")
+            self.store.churned[tk.client] = True
             self.metrics.bump("expired")
             self.metrics.log(now, "expire", client=tk.client, wave=tk.wave,
                              deadline=round(tk.deadline, 6))
@@ -408,30 +398,17 @@ class ParamService:
                                  status=row["status"], value=row["value"],
                                  burn_rate=row["burn_rate"])
 
-    def _note_expired(self, client: int) -> None:
-        if self.store is not None:
-            self.store.churned[client] = True
-        else:
-            self._expired_once.add(client)
-
     def _churn_rejoined(self, client: int) -> bool:
         """Was the client seen churning since its last dispatch? Clears
         the flag (one rejoin count per churn episode)."""
-        if self.store is not None:
-            if self.store.churned[client]:
-                self.store.churned[client] = False
-                return True
-            return False
-        if client in self._expired_once:
-            self._expired_once.discard(client)
+        if self.store.churned[client]:
+            self.store.churned[client] = False
             return True
         return False
 
     def _churned_clients(self) -> List[int]:
-        """Sorted churn set (checkpointing), whichever backend holds it."""
-        if self.store is not None:
-            return [int(c) for c in np.flatnonzero(self.store.churned)]
-        return sorted(int(c) for c in self._expired_once)
+        """Sorted churn set (checkpointing)."""
+        return [int(c) for c in np.flatnonzero(self.store.churned)]
 
     def _resolve(self, tk: Ticket, now: float, expired: bool) -> None:
         """Mark a wave slot done (arrived or expired); when the whole wave

@@ -200,9 +200,8 @@ def restore_service(svc, path) -> None:
     _restore_agent_owner(srv.allocator, flat, aux["ppo1"], "ppo1")
     _restore_agent_owner(srv.intensity, flat, aux["ppo2"], "ppo2")
     srv._round = int(aux["round"])
-    # in place, not reassignment: with a ClientStore, srv._ef aliases
-    # store.ef (one home for sparse per-client codec state) and restore
-    # must not sever that link
+    # in place, not reassignment: srv._ef aliases store.ef (one home for
+    # sparse per-client codec state) and restore must not sever that link
     srv._ef.clear()
     srv._ef.update({
         (c, kind, size): [np.asarray(flat[f"ef/{c}|{kind}|{size}/{i}"])
@@ -214,7 +213,6 @@ def restore_service(svc, path) -> None:
     svc._wave_count = int(aux["wave_count"])
     svc.records = list(aux["records"])
     svc.metrics.unpack(aux["metrics"])
-    svc._expired_once = set(aux["expired_once"])
 
     svc._waves = {}
     for w, info in aux["waves"].items():
@@ -260,17 +258,16 @@ def restore_service(svc, path) -> None:
                            "acc_lite": float(meta["acc_lite"]),
                            "version": int(meta["version"])})
 
-    # rebuild the ClientStore's live slots from the restored tickets so
-    # vectorized expiry / churn checks continue bit-identically (history
-    # counters are observability-only and restart at zero)
-    store = getattr(svc, "store", None)
-    if store is not None:
-        store.reset_slots()
-        for c, tk in svc.tickets.items():
-            store.open_slots([c], tk.wave, [tk.index], tk.version,
-                             tk.deadline)
-        for c in aux["expired_once"]:
-            store.churned[int(c)] = True
+    # rebuild the ClientStore's live slots and churn flags from the
+    # restored tickets and churn set so vectorized expiry / churn checks
+    # continue bit-identically (history counters are observability-only
+    # and restart at zero)
+    store = svc.store
+    store.reset_slots()
+    for c, tk in svc.tickets.items():
+        store.open_slots([c], tk.wave, [tk.index], tk.version, tk.deadline)
+    for c in aux["expired_once"]:
+        store.churned[int(c)] = True
 
 
 def latest_checkpoint(ckpt_dir) -> Optional[str]:

@@ -123,14 +123,11 @@ class EventScheduler:
         # struct-of-arrays client state (DESIGN.md §15): in-flight marks
         # mirror into it, and candidate filtering reads its mask instead
         # of probing a dict per client. participation="sampled" replaces
-        # the O(n) full-population candidate scan with O(k) rejection
-        # sampling over the store — the population-scale dispatch path
-        # (different rng consumption than "full", so it is opt-in).
-        self.store = getattr(server, "store", None)
+        # the O(n) candidate filter with O(k) rejection sampling over the
+        # store — the population-scale dispatch path (different rng
+        # consumption than "full", so it is opt-in).
+        self.store = server.store
         self.participation = participation
-        if participation == "sampled" and self.store is None:
-            raise ValueError("participation='sampled' needs a server with "
-                             "a ClientStore (client_store=True)")
 
         self.t = 0.0
         self.version = 0               # server aggregation count
@@ -195,13 +192,9 @@ class EventScheduler:
         else:
             among = None
             if self.availability is not None or self.inflight:
-                if self.store is not None:
-                    cands = self.store.candidates()
-                    among = (cands if self.availability is None
-                             else [c for c in cands if self._available(c)])
-                else:
-                    among = [c for c in range(cfg.n_clients)
-                             if c not in self.inflight and self._available(c)]
+                cands = self.store.candidates()
+                among = (cands if self.availability is None
+                         else [c for c in cands if self._available(c)])
             clients = self.env.select_clients(k=k, among=among)
         if not clients:
             self._guard_stall()
@@ -268,8 +261,7 @@ class EventScheduler:
                 evs.append(Event(drop_t, DROPOUT, c, w))
             else:
                 evs.append(Event(float(t_arrive[i]), ARRIVAL, c, w))
-        if self.store is not None:
-            self.store.open_slots(clients, w, list(range(m)), plan.version)
+        self.store.open_slots(clients, w, list(range(m)), plan.version)
         self.queue.push_batch(evs)
         info["finish"] = [float(o) for o in offs]
         if pol.name == "deadline":
@@ -422,8 +414,7 @@ class EventScheduler:
         if self.inflight.get(ev.client, (None, None))[0] != ev.wave:
             return                     # stale event: client dropped/requeued
         w, i = self.inflight.pop(ev.client)
-        if self.store is not None:
-            self.store.close_slot(ev.client, "update")
+        self.store.close_slot(ev.client, "update")
         info = self._waves[w]
         info["outstanding"].discard(i)
         info["arrived"].append((i, ev.time))
@@ -453,8 +444,7 @@ class EventScheduler:
             c = plan.clients[i]
             if self.inflight.get(c) == (ev.wave, i):
                 del self.inflight[c]
-                if self.store is not None:
-                    self.store.close_slot(c, "expired")
+                self.store.close_slot(c, "expired")
             self.n_dropped += 1
             if self.health is not None:
                 self.health.note_outcome("expired")
@@ -465,8 +455,7 @@ class EventScheduler:
         if self.inflight.get(ev.client, (None, None))[0] != ev.wave:
             return
         w, i = self.inflight.pop(ev.client)
-        if self.store is not None:
-            self.store.close_slot(ev.client, "dropped")
+        self.store.close_slot(ev.client, "dropped")
         info = self._waves[w]
         info["outstanding"].discard(i)
         self.n_dropped += 1

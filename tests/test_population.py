@@ -1,6 +1,6 @@
-"""Population-scale client state (DESIGN.md §15): SoA-vs-legacy bit
-parity, vectorized latency/event machinery, sampled participation,
-memory shape, and 10k-client scale regressions."""
+"""Population-scale client state (DESIGN.md §15): the fleet and latency
+pin, vectorized event machinery, sampled participation, memory shape,
+and 10k-client scale regressions."""
 import numpy as np
 import pytest
 
@@ -18,74 +18,106 @@ CFG = FLSimConfig(dataset="mnist", n_train=300, n_test=80, n_clients=10,
                   batch_size=16, seed=3)
 
 
-def _teq(a, b):
-    import jax
-    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
-    return len(la) == len(lb) and all(
-        np.asarray(x).tobytes() == np.asarray(y).tobytes()
-        for x, y in zip(la, lb))
-
-
 # --------------------------------------------------------------------- #
-# tentpole pin: SoA store path == legacy dict-of-objects path, bitwise
+# fleet + latency pin: the hex literals were recorded from FLEnvironment
+# built over per-client profile objects, before the ClientStore became
+# the only layout — every number must survive bitwise
 # --------------------------------------------------------------------- #
-def test_store_path_matches_legacy_bitwise():
-    srv_soa = HAPFLServer(FLEnvironment(CFG), seed=3)          # store path
-    srv_leg = HAPFLServer(FLEnvironment(CFG), seed=3,
-                          client_store=False)                  # legacy loop
-    assert srv_soa.store is not None and srv_leg.store is None
-    recs_a = srv_soa.run(3)
-    recs_b = srv_leg.run(3)
-    for a, b in zip(recs_a, recs_b):
-        assert a.clients == b.clients
-        assert a.sizes == b.sizes
-        assert a.intensities == b.intensities
-        assert a.assess_times == b.assess_times
-        assert a.local_times == b.local_times
-        assert a.straggling == b.straggling
-        assert a.reward_ppo1 == b.reward_ppo1
-        assert a.reward_ppo2 == b.reward_ppo2
-        assert a.acc_lite == b.acc_lite
-        assert a.acc_by_size == b.acc_by_size
-        assert a.client_acc == b.client_acc
-    assert _teq(srv_soa.lite_params, srv_leg.lite_params)
-    for s in srv_soa.global_by_size:
-        assert _teq(srv_soa.global_by_size[s], srv_leg.global_by_size[s])
-    assert _teq(srv_soa.allocator.agent.params,
-                srv_leg.allocator.agent.params)
-    assert _teq(srv_soa.intensity.agent.params,
-                srv_leg.intensity.agent.params)
-    # the store recorded what was planned
-    st = srv_soa.store
-    planned = sorted({c for r in recs_a for c in r.clients})
-    assert sorted(np.flatnonzero(st.n_planned > 0).tolist()) == planned
+_PIN_BASE_SPEED = (
+    "0x1.4000000000000p+3", "0x1.290fca9c761f7p+2",
+    "0x1.0000000000000p+0", "0x1.ab08a305b6db4p+0",
+    "0x1.4aa2ffb466100p+0", "0x1.642ae80fd415ap+1",
+    "0x1.7fab7fe3f7a26p+2", "0x1.cc02160cfaba7p+1",
+    "0x1.13c484138704fp+1", "0x1.ef875c9cfd6a6p+2",
+)
+_PIN_DATASET_SIZE = (18, 21, 24, 31, 56, 24, 52, 34, 24, 16)
+_PIN_ASSESS = {
+    0: (
+        "0x1.7b1fa701c3ae2p-4", "0x1.9d15c761d766fp-3",
+        "0x1.138402db3beb1p+0", "0x1.f0106ae535bc4p-1",
+        "0x1.57299ee16b6a5p+1", "0x1.203bf03ded139p-1",
+        "0x1.f799b51093d39p-2", "0x1.888b20e6402c9p-2",
+        "0x1.e7a7b9f484034p-2", "0x1.a947b92c10956p-4",
+    ),
+    7: (
+        "0x1.4122c6c7263c0p-4", "0x1.963d486635e6bp-3",
+        "0x1.2fe52f66c66bdp+0", "0x1.3a23426b3c739p+0",
+        "0x1.5f2135acd0f23p+1", "0x1.e22adea703bcbp-2",
+        "0x1.8338fc3fa085fp-2", "0x1.9209cbbc540aap-2",
+        "0x1.01624b8652f5fp-1", "0x1.fe7a48b5c1de1p-4",
+    ),
+    31: (
+        "0x1.e314e3121bf24p-4", "0x1.34df82fdc9d0bp-2",
+        "0x1.4c639e01c21bbp+0", "0x1.9b47a8eacbf7cp-1",
+        "0x1.d697648b9a816p+0", "0x1.78492bb820cb9p-2",
+        "0x1.f21959ded26a2p-2", "0x1.118658faf4803p-1",
+        "0x1.39bc3daef4720p-1", "0x1.783abf2deba08p-4",
+    ),
+}
+_PIN_LOCAL = {
+    0: (
+        "0x1.1188e940e230dp-1", "0x1.3f428d6589beep+0",
+        "0x1.2a2c75cf712aap+4", "0x1.7f644b5fa4715p+3",
+        "0x1.357c897b216fap+6", "0x1.bd88940ab70adp+0",
+        "0x1.6b5806b0bf03bp+2", "0x1.c713783f030a8p+1",
+        "0x1.5fd6e87af370dp+3", "0x1.9adb406ca8636p+0",
+    ),
+    7: (
+        "0x1.cf64e4b3e5d7ap-2", "0x1.39f81af876287p+0",
+        "0x1.48e31c87948a0p+4", "0x1.e592d765bdad0p+3",
+        "0x1.3cabf8cf0df72p+6", "0x1.74a6c8265be00p+0",
+        "0x1.1760ce16dab93p+2", "0x1.d215584986926p+1",
+        "0x1.7366b02cbf7a1p+3", "0x1.ed2a16f8163f5p+0",
+    ),
+    31: (
+        "0x1.5c8a280d84ad5p-1", "0x1.dd6f94b4e8184p+0",
+        "0x1.67b96d47e17b0p+4", "0x1.3ddd65486acadp+3",
+        "0x1.a868fc6b341b2p+5", "0x1.22d1abfec51e7p+0",
+        "0x1.675fe86b0e6a6p+2", "0x1.3d18faf5c77b0p+2",
+        "0x1.c4b71b8a1cc74p+3", "0x1.6b7827b08772ep+0",
+    ),
+}
 
 
-def test_store_ef_is_shared_with_server():
-    srv = HAPFLServer(FLEnvironment(CFG), seed=3, codec="int8")
-    assert srv._ef is srv.store.ef
-    srv.run(1)
-    assert len(srv.store.ef) > 0        # lossy codec left residuals behind
-    assert srv.store.nbytes() > 0
-
-
-# --------------------------------------------------------------------- #
-# vectorized latency == scalar latency, bitwise
-# --------------------------------------------------------------------- #
-def test_vectorized_latency_matches_scalar_bitwise():
+def test_fleet_and_latency_pinned_bitwise():
     env = FLEnvironment(CFG)
     store, lat = env.store, env.latency
     clients = list(range(CFG.n_clients))
     sizes = ["small" if c % 2 else "large" for c in clients]
     taus = [1 + (c % 5) for c in clients]
+
+    def hexes(a):
+        return tuple(float(x).hex() for x in a)
+
+    assert hexes(store.base_speed) == _PIN_BASE_SPEED
+    assert tuple(store.dataset_size.tolist()) == _PIN_DATASET_SIZE
     for r in (0, 7, 31):
-        vec_a = lat.assessment_times(store, clients, r)
-        vec_l = lat.local_train_times(store, clients, r, sizes, taus)
-        for i, c in enumerate(clients):
-            p = env.profiles[c]
-            assert float(vec_a[i]) == lat.assessment_time(p, r)
-            assert float(vec_l[i]) == lat.local_train_time(
-                p, r, sizes[i], taus[i])
+        assert hexes(lat.assessment_times(store, clients, r)) \
+            == _PIN_ASSESS[r]
+        assert hexes(lat.local_train_times(store, clients, r, sizes,
+                                           taus)) == _PIN_LOCAL[r]
+
+
+def test_synthetic_speeds_match_for_partition():
+    """Both constructors draw base speeds the same way, so a synthetic
+    population has the speed layout of a real partition of equal
+    (n, ratio, seed)."""
+    for n, ratio, seed in ((10, 10.0, 3), (257, 50.0, 0), (1000, 20.0, 11)):
+        syn = ClientStore.synthetic(n, ratio, seed=seed)
+        part = ClientStore.for_partition(n, ratio, np.full(n, 30),
+                                         np.ones(n), seed=seed)
+        assert syn.base_speed.tobytes() == part.base_speed.tobytes()
+
+
+def test_store_ef_is_shared_with_server():
+    srv = HAPFLServer(FLEnvironment(CFG), seed=3, codec="int8")
+    assert srv._ef is srv.store.ef
+    recs = srv.run(1)
+    assert len(srv.store.ef) > 0        # lossy codec left residuals behind
+    assert srv.store.nbytes() > 0
+    # the store recorded what was planned
+    planned = sorted({c for r in recs for c in r.clients})
+    assert sorted(np.flatnonzero(srv.store.n_planned > 0).tolist()) == planned
 
 
 # --------------------------------------------------------------------- #

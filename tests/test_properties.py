@@ -20,7 +20,8 @@ from repro.comm.quantize import dequantize, quantize
 from repro.core.aggregation import (aggregation_weights, fedavg_aggregate,
                                     information_entropy, staleness_weights,
                                     weighted_aggregate)
-from repro.core.latency import ClientProfile, LatencyModel
+from repro.core.latency import LatencyModel
+from repro.core.population import ClientStore
 from repro.core.ppo import discounted_returns
 from repro.launch.hlo_analysis import shape_bytes
 
@@ -66,15 +67,15 @@ def test_weighted_aggregate_convexity(seed, ws):
 @settings(max_examples=30, deadline=None)
 def test_latency_monotone_in_intensity(tau1, tau2):
     lm = LatencyModel({"small": 100.0, "large": 400.0}, 50.0, seed=0)
-    prof = ClientProfile(0, base_speed=2.0, dataset_size=100,
-                         jitter_sigma=0.0, drift_amp=0.0)
-    t1 = lm.local_train_time(prof, 0, "small", tau1)
-    t2 = lm.local_train_time(prof, 0, "small", tau2)
+    one = ClientStore(base_speed=[2.0], dataset_size=[100], entropy=[1.0],
+                      jitter_sigma=0.0, drift_amp=0.0)
+    t1 = lm.local_train_times(one, [0], 0, ["small"], [tau1])[0]
+    t2 = lm.local_train_times(one, [0], 0, ["small"], [tau2])[0]
     if tau1 < tau2:
         assert t1 < t2
     lm2 = LatencyModel({"small": 100.0, "large": 400.0}, 50.0, seed=0)
-    assert (lm2.local_train_time(prof, 0, "large", tau1)
-            > lm2.local_train_time(prof, 0, "small", tau1))
+    assert (lm2.local_train_times(one, [0], 0, ["large"], [tau1])[0]
+            > lm2.local_train_times(one, [0], 0, ["small"], [tau1])[0])
 
 
 @given(st.lists(st.floats(-10, 10), min_size=1, max_size=20),

@@ -56,14 +56,21 @@ def test_latency_draws_are_query_order_independent():
     """Prerequisite for parity: jitter is a pure function of
     (client, round), so asking in any order/multiplicity matches."""
     env = FLEnvironment(CFG)
-    p = env.profiles[2]
-    v1 = env.latency.local_train_time(p, 7, "small", 3)
-    a1 = env.latency.assessment_time(p, 7)
-    for q, r in itertools.product(env.profiles, range(5)):
-        env.latency.assessment_time(q, r)
-        env.latency.local_train_time(q, r, "large", 2)
-    assert env.latency.local_train_time(p, 7, "small", 3) == v1
-    assert env.latency.assessment_time(p, 7) == a1
+    lat, store = env.latency, env.store
+    v1 = lat.local_train_times(store, [2], 7, ["small"], [3])[0]
+    a1 = lat.assessment_times(store, [2], 7)[0]
+    everyone = list(range(CFG.n_clients))
+    for q, r in itertools.product(everyone, range(5)):
+        lat.assessment_times(store, [q], r)
+        lat.local_train_times(store, [q], r, ["large"], [2])
+    assert lat.local_train_times(store, [2], 7, ["small"], [3])[0] == v1
+    assert lat.assessment_times(store, [2], 7)[0] == a1
+    # inside a cohort, in any order, the client's draw is the same
+    cohort = everyone[::-1]
+    i = cohort.index(2)
+    assert lat.assessment_times(store, cohort, 7)[i] == a1
+    assert lat.local_train_times(store, cohort, 7, ["small"] * len(cohort),
+                                 [3] * len(cohort))[i] == v1
 
 
 # --------------------------------------------------------------------- #
